@@ -139,7 +139,8 @@ def check_trace_overhead(threshold: float = DEFAULT_TRACE_OVERHEAD,
     Every ``fit()`` call builds fresh jitted step functions, so XLA
     recompiles per call — and compile jitter (~10% of a multi-second
     compile) would drown a 2% execution budget. The gate therefore
-    points JAX's persistent compilation cache at a temp dir first: after
+    points JAX's persistent compilation cache at its one directory first
+    (``repro.launch.cache``): after
     one warm-up per arm, every XLA compile is a disk hit and both arms'
     walls measure trace + dispatch + kernels. Scoring is the MEDIAN of
     per-pair relative deltas over ``repeats`` pairs; within a pair the
@@ -150,18 +151,14 @@ def check_trace_overhead(threshold: float = DEFAULT_TRACE_OVERHEAD,
     second. Single-sample estimators — min-of-N included — measurably
     flake at a 2% resolution on shared CI runners; this one holds.
     """
-    import tempfile
-
     import jax
     import numpy as np
 
-    jax.config.update("jax_compilation_cache_dir",
-                      tempfile.mkdtemp(prefix="trace_overhead_cache_"))
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:  # older jax spells the size knob differently (or not at all)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except AttributeError:
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
     from repro.api import fit
 

@@ -22,6 +22,8 @@ def main() -> None:
                     help="table2|table3|minibatch|kernels|eim11|scenarios")
     args = ap.parse_args()
 
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (bench_eim11, bench_kernels, bench_minibatch,
                             bench_table2, bench_table3)
 

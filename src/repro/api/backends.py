@@ -86,16 +86,6 @@ def check_uplink_wire(wire, dtype: str = "float32") -> str:
     return wire
 
 
-def _shard_map(fn, mesh: Mesh, in_specs, out_specs):
-    """Version-compat shard_map (jax.shard_map vs jax.experimental)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def mesh_comm(mesh: Mesh, axis_names: Optional[Tuple[str, ...]] = None
               ) -> MeshCluster:
     """MeshCluster over the given mesh axes (all axes by default)."""
@@ -224,8 +214,10 @@ class MeshBackend:
             tree, marks)
 
     def compile(self, fn, in_marks, out_marks, donate=()):
-        mapped = _shard_map(fn, self.mesh, in_specs=self._specs(in_marks),
-                            out_specs=self._specs(out_marks))
+        mapped = jax.shard_map(fn, mesh=self.mesh,
+                               in_specs=self._specs(in_marks),
+                               out_specs=self._specs(out_marks),
+                               check_vma=False)
         return jax.jit(mapped, donate_argnums=donate)
 
 

@@ -2,10 +2,10 @@
 
 The paper's synthetic benchmark draws ten million points from a
 k-spherical-Gaussian mixture in R^15 with Zipf(γ=1.5) cluster weights and
-σ=0.001; real datasets are multi-million-point UCI tables. This container
-is CPU-only and offline, so the benchmark presets scale n down while
-keeping every ratio (ε, δ, k, zipf γ, σ) from the paper; the full-size
-shapes are exercised by the dry-run.
+σ=0.001; real datasets are multi-million-point UCI tables. The presets
+below scale n down for CPU runs while keeping every ratio (ε, δ, k,
+zipf γ, σ) from the paper; ``chip_smoke.py`` runs the published size,
+n = 10,240,000, on one TPU v5e.
 """
 from __future__ import annotations
 

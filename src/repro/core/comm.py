@@ -129,7 +129,9 @@ def _concrete_sum(counts) -> Optional[int]:
     """``int(sum(counts))`` when eager, None under tracing."""
     try:
         return int(jax.device_get(jnp.sum(counts)))
-    except Exception:  # ConcretizationTypeError and friends, jax-version safe
+    except (jax.errors.ConcretizationTypeError,
+            jax.errors.TracerArrayConversionError,
+            jax.errors.TracerIntegerConversionError):
         return None
 
 

@@ -18,7 +18,7 @@ conformance-suite runtime only — so tables are keyed by
 consulted on another.
 
 Usage:
-    python -m repro.kernels.autotune            # full sweep -> ~/.cache
+    python -m repro.kernels.autotune            # full sweep -> .cache/repro
     python -m repro.kernels.autotune --quick    # small-n sweep
     python -m repro.kernels.autotune --package  # write the committed table
     make autotune
@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import tuning
+from repro.kernels import ops, tuning
 
 # Candidate panel grids (all 128-tile multiples; clamp_bn round-trips).
 CANDIDATE_BN = (128, 256, 512, 1024)
@@ -97,10 +97,6 @@ def _chunk_vmem_bytes(bn: int, kc: int, d: int, k: int, dtype: str) -> int:
             + kp * d * 4 + kp * 4)       # walk-resident accumulators
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _sweep_block_bucket(d: int, k: int, dtype: str, n: int,
                         timer: Timer) -> Optional[dict]:
     """Best (bn, bk) for one (d, k, dtype) bucket, or None if every
@@ -112,7 +108,7 @@ def _sweep_block_bucket(d: int, k: int, dtype: str, n: int,
     x = jnp.asarray(rng.normal(size=(n, d)), jnp.dtype(dtype))
     c = jnp.asarray(rng.normal(size=(k, d)), jnp.dtype(dtype))
     w = jnp.ones((n,), jnp.float32)
-    interpret = _interpret()
+    interpret = not ops.on_tpu()
 
     best = None
     for bn in CANDIDATE_BN:
@@ -143,7 +139,7 @@ def _sweep_chunk_bucket(d: int, dtype: str, n: int,
     x = jnp.asarray(rng.normal(size=(n, d)), jnp.dtype(dtype))
     c = jnp.asarray(rng.normal(size=(k, d)), jnp.dtype(dtype))
     w = jnp.ones((n,), jnp.float32)
-    interpret = _interpret()
+    interpret = not ops.on_tpu()
 
     best = None
     for bn in CANDIDATE_CHUNK_BN:
@@ -216,7 +212,7 @@ _ENSURED = set()
 
 def ensure_tuned(backend: str) -> None:
     """REPRO_AUTOTUNE=force miss handler: quick-sweep this backend once
-    per process and cache the winners under ``~/.cache/repro``."""
+    per process and cache the winners as the local override."""
     if backend in _ENSURED:
         return
     _ENSURED.add(backend)

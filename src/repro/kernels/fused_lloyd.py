@@ -13,11 +13,6 @@ kernels here each make exactly ONE grid walk over point panels:
   accumulate per-center ``(sums, counts)`` plus the weighted cost — one HBM
   read of ``x`` per Lloyd iteration instead of two, and the (n,) assignment
   vector never round-trips through HBM.
-* ``fused_assign_reduce_pipelined``: the same math with the point/weight
-  stream driven by *explicit double-buffered HBM->VMEM DMA* (two panel
-  slots, the next panel's copy in flight while the current one computes)
-  instead of BlockSpec streaming — the big-n variant ops.py dispatches to
-  when the walk spans multiple panels.
 * ``remove_below``: per (machine, panel), compute ``min_j rho(x, C)^2``,
   compare against the broadcast threshold ``v``, AND into the ``alive``
   mask, and accumulate per-machine live counts — the (m, p) distance array
@@ -26,8 +21,6 @@ kernels here each make exactly ONE grid walk over point panels:
   the running min-d2 against the newly chosen center(s) AND totals the
   weighted sampling mass for the next categorical draw — fused here into
   one sweep of ``x`` instead of a distance pass plus three (n,) passes.
-  ``update_min_dist_pipelined`` double-buffers the input stream AND the
-  (n,) output stream (per-panel VMEM->HBM write-back DMA).
 * ``*_chunked``: big-k variants for EIM11-sized center sets that do not
   fit VMEM. The center set is tiled through VMEM in ``tuning.chunk_sizes``
   panels with a running (min, argmin) per point panel; the assign-reduce
@@ -42,6 +35,12 @@ All kernels accept float32, bfloat16 or float16 points/centers (every
 ``UPLINK_DTYPES`` precision) and accumulate in float32 (inputs are
 widened on load from VMEM, never in HBM), so reduced-precision uplink
 payloads are clustered without an upcast materializing 2x the bytes.
+
+Every walk streams its panels through BlockSpecs, which the TPU pipeline
+already double-buffers (panel i+1's HBM->VMEM copy runs while panel i
+computes). Hand-written DMA loops cannot replace them at small d: Mosaic
+refuses a row slice of an HBM array whose minor dim is not a multiple of
+128 lanes (the paper's d = 15).
 
 Block sizes come from ``kernels.tuning`` (measured table first, analytic
 fallback); every wrapper also takes explicit static size overrides
@@ -62,37 +61,92 @@ from repro.kernels.tuning import block_sizes, chunk_sizes, clamp_bn
 
 _BIG = 3.0e38  # plain float so the kernels capture no traced constants
 
+# Every distance and scatter product runs at full f32 precision: the
+# paper's data sits ~5 from the origin with within-cluster d2 ~ 1e-5, so
+# the single bf16 MXU pass a TPU takes by default would bury the removal
+# threshold and the costs in the cross term's rounding error.
+_HI = jax.lax.Precision.HIGHEST
+
 # The single-walk chunked kernel keeps the full (kp, d) + (kp,)
 # accumulators resident in VMEM; center sets whose accumulators exceed
 # this fall back to the legacy two-walk scatter variant.
 _CHUNK_ACC_BUDGET = 6 * 2**20
 
+# TPU layout: centers sit on sublanes and points on lanes inside every
+# kernel, so each per-point quantity (weights, min-d2, argmin, masks) is a
+# lane-dense (1, bn) row block of a (1, n) or (m, 1, p) array; per-center
+# quantities are (kp, 1) columns and scalars are (1, 1) tiles written
+# whole (Mosaic refuses 1-D blocks that disagree with XLA's tiling and
+# scalar stores to VMEM).
+
+
+def _row(v, n_pad: int, dtype=jnp.float32):
+    """(n,) per-point vector -> (1, n_pad) row, zero padded."""
+    return jnp.pad(v.astype(dtype), (0, n_pad - v.shape[0]))[None, :]
+
+
+def _valid_col(c_valid, k: int, kp: int):
+    """(k,) validity mask -> (kp, 1) float32 column; padded centers are
+    invalid (0). Widened here so no int8 block reaches the kernel."""
+    cv = (jnp.ones((k,), jnp.float32) if c_valid is None
+          else c_valid.astype(jnp.float32))
+    return jnp.pad(cv, (0, kp - k))[:, None]
+
+
+def _partial_d2(x, c, cv):
+    """(kp, bn) masked ||c||^2 - 2 c.x and the (1, bn) ||x||^2 row."""
+    dots = jax.lax.dot_general(
+        c, x, (((1,), (1,)), ((), ())), precision=_HI,
+        preferred_element_type=jnp.float32)         # (kp, bn)
+    x2 = jax.lax.dot_general(
+        jnp.ones((1, x.shape[1]), jnp.float32), x * x,
+        (((1,), (1,)), ((), ())), precision=_HI,
+        preferred_element_type=jnp.float32)         # (1, bn)
+    c2 = jnp.sum(c * c, axis=1, keepdims=True)      # (kp, 1)
+    return jnp.where(cv > 0, c2 - 2.0 * dots, _BIG), x2
+
+
+def _panel_dmin(x, c, cv):
+    """(1, bn) masked min squared distance against a center panel."""
+    part, x2 = _partial_d2(x, c, cv)
+    return jnp.maximum(jnp.min(part, axis=0, keepdims=True) + x2, 0.0)
+
 
 def _panel_min(x, c, cv):
-    """(bn,) masked min squared distance + argmin against resident centers."""
-    dots = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    x2 = jnp.sum(x * x, axis=1, keepdims=True)      # (bn, 1)
-    c2 = jnp.sum(c * c, axis=1)[None, :]            # (1, kp)
-    d2 = x2 - 2.0 * dots + c2                       # (bn, kp)
-    d2 = jnp.where(cv[None, :] != 0, d2, _BIG)
-    return jnp.maximum(jnp.min(d2, axis=1), 0.0), jnp.argmin(d2, axis=1)
+    """(1, bn) masked min squared distance + (1, bn) int32 argmin."""
+    part, x2 = _partial_d2(x, c, cv)
+    dmin = jnp.maximum(jnp.min(part, axis=0, keepdims=True) + x2, 0.0)
+    return dmin, jnp.argmin(part, axis=0, keepdims=True).astype(jnp.int32)
 
 
-def _assign_reduce_panel(x, w, c, cv, kp):
-    """One panel's fused contribution: ((kp, d) sums, (kp,) cnt, () cost)."""
+def _onehot(a, w, rows: int, offset=0):
+    """(rows, bn) weighted one-hot of the (1, bn) assignment row."""
+    centers = jax.lax.broadcasted_iota(jnp.int32, (rows, a.shape[1]), 0)
+    return jnp.where(centers + offset == a, w, 0.0)
+
+
+def _scatter(onehot, x):
+    """(rows, d) weighted sums of the one-hot's points."""
+    return jax.lax.dot_general(
+        onehot, x, (((1,), (0,)), ((), ())), precision=_HI,
+        preferred_element_type=jnp.float32)
+
+
+def _rowsum(v):
+    """Sum of each row, kept 2-D: (r, bn) -> (r, 1)."""
+    return jnp.sum(v, axis=1, keepdims=True)
+
+
+def _assign_reduce_panel(x, w, c, cv):
+    """One panel's fused contribution: ((kp, d) sums, (kp, 1) cnt,
+    (1, 1) cost)."""
     dmin, a = _panel_min(x, c, cv)
-    centers = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], kp), 1)
-    onehot = (a.astype(jnp.int32)[:, None] == centers
-              ).astype(jnp.float32) * w[:, None]    # (bn, kp)
-    sums = jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)          # (kp, d)
-    return sums, jnp.sum(onehot, axis=0), jnp.sum(w * dmin)
+    onehot = _onehot(a, w, c.shape[0])              # (kp, bn)
+    return _scatter(onehot, x), _rowsum(onehot), _rowsum(w * dmin)
 
 
 def _fused_kernel(x_ref, w_ref, c_ref, cv_ref,
-                  sums_ref, cnt_ref, cost_ref, *, kp: int):
+                  sums_ref, cnt_ref, cost_ref):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -102,36 +156,52 @@ def _fused_kernel(x_ref, w_ref, c_ref, cv_ref,
         cost_ref[...] = jnp.zeros(cost_ref.shape, jnp.float32)
 
     x = x_ref[...].astype(jnp.float32)              # (bn, d)
-    w = w_ref[...].astype(jnp.float32)              # (bn,)
     c = c_ref[...].astype(jnp.float32)              # (kp, d)
-    sums, cnt, cost = _assign_reduce_panel(x, w, c, cv_ref[...], kp)
+    sums, cnt, cost = _assign_reduce_panel(x, w_ref[...], c, cv_ref[...])
     sums_ref[...] += sums
     cnt_ref[...] += cnt
-    cost_ref[0, 0] += cost
+    cost_ref[...] += cost
 
 
 def _resident_bn(d: int, k: int, n: int, dtype, bn: Optional[int]) -> int:
     """Point-panel size for the resident-center kernels: the tuned (d, k)
-    entry unless overridden, shrunk so the (bn, kp) one-hot panel stays
+    entry unless overridden, shrunk so the (kp, bn) one-hot panel stays
     inside the VMEM budget, then clamped toward n."""
     if bn is None:
         bn, _ = block_sizes(d, k, str(dtype))
-        if -(-k // 128) * 128 >= 512:   # keep the (bn, kp) one-hot panel
+        if -(-k // 128) * 128 >= 512:   # keep the (kp, bn) one-hot panel
             bn = min(bn, 256)           # inside the VMEM budget
     return clamp_bn(bn, n)
 
 
-def _pad_points(x, w, c, c_valid, bn):
+def _pad_points(x, w, c, c_valid, bn, kp=None):
+    """Pad points to whole panels and centers to ``kp`` rows (default the
+    128-row tile): ((n_pad, d) x, (1, n_pad) w (None without weights),
+    (kp, d) c, (kp, 1) valid, kp)."""
     n, _ = x.shape
     k = c.shape[0]
-    cv = (jnp.ones((k,), jnp.int8) if c_valid is None
-          else c_valid.astype(jnp.int8))
-    kp = -(-k // 128) * 128
-    xp = jnp.pad(x, ((0, -n % bn), (0, 0)))
-    wp = jnp.pad(w, (0, -n % bn))                    # weight-0 rows are no-ops
+    kp = -(-k // 128) * 128 if kp is None else kp
+    n_pad = n + (-n % bn)
+    xp = jnp.pad(x, ((0, n_pad - n), (0, 0)))
+    wp = None if w is None else _row(w, n_pad)       # weight-0 rows: no-ops
     cp = jnp.pad(c, ((0, kp - k), (0, 0)))
-    cvp = jnp.pad(cv, (0, kp - k))                   # padded centers invalid
-    return xp, wp, cp, cvp, kp
+    return xp, wp, cp, _valid_col(c_valid, k, kp), kp
+
+
+def _pad_machines(x, c, alive, c_valid, bn, kp):
+    """(m, p, d) removal inputs -> padded points, (m, 1, p_pad) int32 alive
+    rows (padding is dead), (kp, d) centers and their (kp, 1) mask."""
+    _, p, _ = x.shape
+    k = c.shape[0]
+    xp = jnp.pad(x, ((0, 0), (0, -p % bn), (0, 0)))
+    ap = jnp.pad(alive.astype(jnp.int32), ((0, 0), (0, -p % bn)))[:, None]
+    cp = jnp.pad(c, ((0, kp - k), (0, 0)))
+    return xp, ap, cp, _valid_col(c_valid, k, kp)
+
+
+def _scalar(v):
+    """A () threshold as the (1, 1) float32 tile the kernels compare to."""
+    return jnp.reshape(v, (1, 1)).astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bn"))
@@ -148,117 +218,27 @@ def fused_assign_reduce_pallas(x: jax.Array, w: jax.Array, c: jax.Array,
 
     grid = (xp.shape[0] // bn,)
     sums, counts, cost = pl.pallas_call(
-        functools.partial(_fused_kernel, kp=kp),
+        _fused_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
             pl.BlockSpec((kp, d), lambda i: (0, 0)),
-            pl.BlockSpec((kp,), lambda i: (0,)),
+            pl.BlockSpec((kp, 1), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((kp, d), lambda i: (0, 0)),
-            pl.BlockSpec((kp,), lambda i: (0,)),
+            pl.BlockSpec((kp, 1), lambda i: (0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((kp, d), jnp.float32),
-            jax.ShapeDtypeStruct((kp,), jnp.float32),
+            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
     )(xp, wp, cp, cvp)
-    return sums[:k], counts[:k], cost[0, 0]
-
-
-def _fused_pipelined_kernel(x_hbm, w_hbm, c_ref, cv_ref,
-                            sums_ref, cnt_ref, cost_ref,
-                            xs, ws, xsem, wsem, *, bn: int, kp: int,
-                            nsteps: int):
-    """Single-program grid walk with explicit double-buffered input DMA:
-    panel i+1's HBM->VMEM copies start before panel i's compute."""
-    sums_ref[...] = jnp.zeros(sums_ref.shape, jnp.float32)
-    cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.float32)
-    cost_ref[...] = jnp.zeros(cost_ref.shape, jnp.float32)
-    c = c_ref[...].astype(jnp.float32)
-    cv = cv_ref[...]
-
-    def x_dma(slot, i):
-        return pltpu.make_async_copy(
-            x_hbm.at[pl.ds(i * bn, bn)], xs.at[slot], xsem.at[slot])
-
-    def w_dma(slot, i):
-        return pltpu.make_async_copy(
-            w_hbm.at[pl.ds(i * bn, bn)], ws.at[slot], wsem.at[slot])
-
-    x_dma(0, 0).start()
-    w_dma(0, 0).start()
-
-    def body(i, _):
-        slot = jax.lax.rem(i, 2)
-        nxt = jax.lax.rem(i + 1, 2)
-
-        @pl.when(i + 1 < nsteps)
-        def _prefetch():
-            x_dma(nxt, i + 1).start()
-            w_dma(nxt, i + 1).start()
-
-        x_dma(slot, i).wait()
-        w_dma(slot, i).wait()
-        x = xs[slot].astype(jnp.float32)
-        w = ws[slot].astype(jnp.float32)
-        sums, cnt, cost = _assign_reduce_panel(x, w, c, cv, kp)
-        sums_ref[...] += sums
-        cnt_ref[...] += cnt
-        cost_ref[0, 0] += cost
-        return 0
-
-    jax.lax.fori_loop(0, nsteps, body, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "bn"))
-def fused_assign_reduce_pipelined_pallas(
-        x: jax.Array, w: jax.Array, c: jax.Array,
-        c_valid: Optional[jax.Array] = None,
-        *, interpret: bool = False, bn: Optional[int] = None
-        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """``fused_assign_reduce`` with manual double-buffered HBM->VMEM DMA
-    over the point/weight stream (same contract, same accumulators)."""
-    n, d = x.shape
-    k = c.shape[0]
-    bn = _resident_bn(d, k, n, x.dtype, bn)
-    xp, wp, cp, cvp, kp = _pad_points(x, w.astype(jnp.float32), c,
-                                      c_valid, bn)
-    nsteps = xp.shape[0] // bn
-
-    sums, counts, cost = pl.pallas_call(
-        functools.partial(_fused_pipelined_kernel, bn=bn, kp=kp,
-                          nsteps=nsteps),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),    # x stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),    # w stays in HBM
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((kp, d), jnp.float32),
-            jax.ShapeDtypeStruct((kp,), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, bn, d), xp.dtype),        # double-buffered x
-            pltpu.VMEM((2, bn), jnp.float32),        # double-buffered w
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret,
-    )(xp, wp, cp, cvp)
-    return sums[:k], counts[:k], cost[0, 0]
+    return sums[:k], counts[:k, 0], cost[0, 0]
 
 
 def _remove_kernel(x_ref, a_ref, c_ref, cv_ref, v_ref, out_ref, live_ref):
@@ -269,10 +249,10 @@ def _remove_kernel(x_ref, a_ref, c_ref, cv_ref, v_ref, out_ref, live_ref):
         live_ref[...] = jnp.zeros(live_ref.shape, jnp.int32)
 
     x = x_ref[0].astype(jnp.float32)                 # (bn, d)
-    dmin, _ = _panel_min(x, c_ref[...].astype(jnp.float32), cv_ref[...])
-    keep = (a_ref[0] != 0) & (dmin > v_ref[0, 0])
-    out_ref[0] = keep.astype(jnp.int8)
-    live_ref[0] += jnp.sum(keep.astype(jnp.int32))
+    dmin = _panel_dmin(x, c_ref[...].astype(jnp.float32), cv_ref[...])
+    keep = ((a_ref[0] != 0) & (dmin > v_ref[...])).astype(jnp.int32)
+    out_ref[0] = keep                                # (1, bn)
+    live_ref[0] += _rowsum(keep)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bn"))
@@ -288,18 +268,9 @@ def remove_below_pallas(x: jax.Array, c: jax.Array, alive: jax.Array,
     """
     m, p, d = x.shape
     k = c.shape[0]
-    if c_valid is None:
-        c_valid = jnp.ones((k,), jnp.int8)
-    else:
-        c_valid = c_valid.astype(jnp.int8)
-
     bn = _resident_bn(d, k, p, x.dtype, bn)
     kp = -(-k // 128) * 128
-    xp = jnp.pad(x, ((0, 0), (0, -p % bn), (0, 0)))
-    ap = jnp.pad(alive.astype(jnp.int8), ((0, 0), (0, -p % bn)))  # pad = dead
-    cp = jnp.pad(c, ((0, kp - k), (0, 0)))
-    cvp = jnp.pad(c_valid, (0, kp - k))
-    vv = jnp.reshape(v, (1, 1)).astype(jnp.float32)
+    xp, ap, cp, cvp = _pad_machines(x, c, alive, c_valid, bn, kp)
 
     grid = (m, xp.shape[1] // bn)                    # panel axis innermost
     alive_new, live = pl.pallas_call(
@@ -307,22 +278,22 @@ def remove_below_pallas(x: jax.Array, c: jax.Array, alive: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bn, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, bn), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1, bn), lambda i, j: (i, 0, j)),
             pl.BlockSpec((kp, d), lambda i, j: (0, 0)),
-            pl.BlockSpec((kp,), lambda i, j: (0,)),
+            pl.BlockSpec((kp, 1), lambda i, j: (0, 0)),
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
+            pl.BlockSpec((1, 1, bn), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m, xp.shape[1]), jnp.int8),
-            jax.ShapeDtypeStruct((m,), jnp.int32),
+            jax.ShapeDtypeStruct(ap.shape, jnp.int32),
+            jax.ShapeDtypeStruct((m, 1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(xp, ap, cp, cvp, vv)
-    return alive_new[:, :p].astype(bool), live
+    )(xp, ap, cp, cvp, _scalar(v))
+    return alive_new[:, 0, :p].astype(bool), live[:, 0, 0]
 
 
 def _update_kernel(x_ref, w_ref, d2_ref, c_ref, cv_ref,
@@ -334,18 +305,18 @@ def _update_kernel(x_ref, w_ref, d2_ref, c_ref, cv_ref,
         mass_ref[...] = jnp.zeros(mass_ref.shape, jnp.float32)
 
     x = x_ref[...].astype(jnp.float32)              # (bn, d)
-    w = w_ref[...].astype(jnp.float32)              # (bn,)
     c = c_ref[...].astype(jnp.float32)              # (kp, d)
     cv = cv_ref[...]
-    cand, _ = _panel_min(x, c, cv)
-    prev = d2_ref[...].astype(jnp.float32)
+    cand = _panel_dmin(x, c, cv)
+    prev = d2_ref[...]
     # with every center masked off the update is a no-op (matches the
     # inf-masked oracle exactly even when the caller's running d2 is
     # still +inf); the mask is checked directly — cand's _BIG sentinel
     # cannot distinguish "no valid center" from a genuinely huge distance
-    new = jnp.where(jnp.any(cv != 0), jnp.minimum(prev, cand), prev)
+    any_valid = jnp.max(cv, axis=0, keepdims=True) > 0      # (1, 1)
+    new = jnp.where(any_valid, jnp.minimum(prev, cand), prev)
     out_ref[...] = new
-    mass_ref[0, 0] += jnp.sum(w * new)
+    mass_ref[...] += _rowsum(w_ref[...] * new)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bn"))
@@ -363,8 +334,8 @@ def update_min_dist_pallas(x: jax.Array, w: jax.Array, c: jax.Array,
     n, d = x.shape
     kc = c.shape[0]
     bn = _resident_bn(d, kc, n, x.dtype, bn)
-    dp = jnp.pad(d2.astype(jnp.float32), (0, -n % bn))  # pad 0, not inf:
-    xp, wp, cp, cvp, kp = _pad_points(x, w, c, c_valid, bn)  # 0*w_pad = 0
+    xp, wp, cp, cvp, kp = _pad_points(x, w, c, c_valid, bn)
+    dp = _row(d2, xp.shape[0])           # pad 0, not inf: 0 * w_pad = 0
 
     grid = (xp.shape[0] // bn,)
     out, mass = pl.pallas_call(
@@ -372,128 +343,32 @@ def update_min_dist_pallas(x: jax.Array, w: jax.Array, c: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
             pl.BlockSpec((kp, d), lambda i: (0, 0)),
-            pl.BlockSpec((kp,), lambda i: (0,)),
+            pl.BlockSpec((kp, 1), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((xp.shape[0],), jnp.float32),
+            jax.ShapeDtypeStruct(dp.shape, jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
     )(xp, wp, dp, cp, cvp)
-    return out[:n], mass[0, 0]
+    return out[0, :n], mass[0, 0]
 
 
-def _update_pipelined_kernel(x_hbm, w_hbm, d2_hbm, c_ref, cv_ref,
-                             out_hbm, mass_ref,
-                             xs, ws, ds, outs, xsem, wsem, dsem, osem,
-                             *, bn: int, nsteps: int):
-    """Double-buffered D²-seeding walk: inputs stream in over two DMA
-    slots and the updated (bn,) min-d2 panels stream back out VMEM->HBM,
-    also double-buffered (a slot is reused only after its previous
-    write-back completed)."""
-    mass_ref[...] = jnp.zeros(mass_ref.shape, jnp.float32)
-    c = c_ref[...].astype(jnp.float32)
-    cv = cv_ref[...]
-    any_valid = jnp.any(cv != 0)
-
-    def in_dma(slot, i):
-        return (pltpu.make_async_copy(x_hbm.at[pl.ds(i * bn, bn)],
-                                      xs.at[slot], xsem.at[slot]),
-                pltpu.make_async_copy(w_hbm.at[pl.ds(i * bn, bn)],
-                                     ws.at[slot], wsem.at[slot]),
-                pltpu.make_async_copy(d2_hbm.at[pl.ds(i * bn, bn)],
-                                      ds.at[slot], dsem.at[slot]))
-
-    def out_dma(slot, i):
-        return pltpu.make_async_copy(
-            outs.at[slot], out_hbm.at[pl.ds(i * bn, bn)], osem.at[slot])
-
-    for dma in in_dma(0, 0):
-        dma.start()
-
-    def body(i, _):
-        slot = jax.lax.rem(i, 2)
-        nxt = jax.lax.rem(i + 1, 2)
-
-        @pl.when(i + 1 < nsteps)
-        def _prefetch():
-            for dma in in_dma(nxt, i + 1):
-                dma.start()
-
-        for dma in in_dma(slot, i):
-            dma.wait()
-        x = xs[slot].astype(jnp.float32)
-        w = ws[slot].astype(jnp.float32)
-        prev = ds[slot]
-        cand, _ = _panel_min(x, c, cv)
-        new = jnp.where(any_valid, jnp.minimum(prev, cand), prev)
-
-        @pl.when(i >= 2)                     # slot reused: write-back of
-        def _drain():                        # panel i-2 must be done
-            out_dma(slot, i - 2).wait()
-
-        outs[slot] = new
-        out_dma(slot, i).start()
-        mass_ref[0, 0] += jnp.sum(w * new)
-        return 0
-
-    jax.lax.fori_loop(0, nsteps, body, 0)
-    for t in range(max(0, nsteps - 2), nsteps):  # static epilogue drain
-        out_dma(t % 2, t).wait()
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "bn"))
-def update_min_dist_pipelined_pallas(
-        x: jax.Array, w: jax.Array, c: jax.Array, d2: jax.Array,
-        c_valid: Optional[jax.Array] = None,
-        *, interpret: bool = False, bn: Optional[int] = None
-        ) -> Tuple[jax.Array, jax.Array]:
-    """``update_min_dist`` with double-buffered input AND output DMA."""
-    n, d = x.shape
-    kc = c.shape[0]
-    bn = _resident_bn(d, kc, n, x.dtype, bn)
-    dp = jnp.pad(d2.astype(jnp.float32), (0, -n % bn))
-    xp, wp, cp, cvp, kp = _pad_points(x, w.astype(jnp.float32), c,
-                                      c_valid, bn)
-    nsteps = xp.shape[0] // bn
-
-    out, mass = pl.pallas_call(
-        functools.partial(_update_pipelined_kernel, bn=bn, nsteps=nsteps),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),    # streamed back by DMA
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((xp.shape[0],), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, bn, d), xp.dtype),
-            pltpu.VMEM((2, bn), jnp.float32),
-            pltpu.VMEM((2, bn), jnp.float32),
-            pltpu.VMEM((2, bn), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret,
-    )(xp, wp, dp, cp, cvp)
-    return out[:n], mass[0, 0]
+def _running_min(x, c_ref, cv_ref, d2_scr, idx_scr, offset):
+    """Fold one center chunk into the panel's running (min, argmin)."""
+    local_min, local_arg = _panel_min(x, c_ref[...].astype(jnp.float32),
+                                      cv_ref[...])
+    prev = d2_scr[...]
+    better = local_min < prev
+    idx_scr[...] = jnp.where(better, local_arg + offset, idx_scr[...])
+    d2_scr[...] = jnp.where(better, local_min, prev)
 
 
 def _fused_chunked_kernel(x_ref, w_ref, c_ref, cv_ref,
@@ -519,41 +394,17 @@ def _fused_chunked_kernel(x_ref, w_ref, c_ref, cv_ref,
         idx_scr[...] = jnp.zeros(idx_scr.shape, jnp.int32)
 
     x = x_ref[...].astype(jnp.float32)              # (bn, d) resident over j
-    local_min, local_arg = _panel_min(x, c_ref[...].astype(jnp.float32),
-                                      cv_ref[...])
-    local_arg = local_arg.astype(jnp.int32) + j * bk
-
-    prev = d2_scr[...]
-    better = local_min < prev
-    idx_scr[...] = jnp.where(better, local_arg, idx_scr[...])
-    d2_scr[...] = jnp.where(better, local_min, prev)
+    _running_min(x, c_ref, cv_ref, d2_scr, idx_scr, j * bk)
 
     @pl.when(j == nc - 1)
-    def _scatter():                                 # argmin now final
-        w = w_ref[...].astype(jnp.float32)
+    def _scatter_chunks():                          # argmin now final
+        w = w_ref[...]
         a = idx_scr[...]
-        centers = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], bk), 1)
         for jj in range(nc):                        # static chunk unroll
-            onehot = ((a - jj * bk)[:, None] == centers
-                      ).astype(jnp.float32) * w[:, None]
-            sums_ref[jj * bk:(jj + 1) * bk, :] += jax.lax.dot_general(
-                onehot, x, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            cnt_ref[jj * bk:(jj + 1) * bk] += jnp.sum(onehot, axis=0)
-        cost_ref[0, 0] += jnp.sum(w * d2_scr[...])
-
-
-def _pad_chunked(x, w, c, c_valid, bn, bk):
-    n, _ = x.shape
-    k = c.shape[0]
-    cv = (jnp.ones((k,), jnp.int8) if c_valid is None
-          else c_valid.astype(jnp.int8))
-    kp = -(-k // bk) * bk
-    xp = jnp.pad(x, ((0, -n % bn), (0, 0)))
-    wp = jnp.pad(w, (0, -n % bn))                    # weight-0 rows are no-ops
-    cp = jnp.pad(c, ((0, kp - k), (0, 0)))
-    cvp = jnp.pad(cv, (0, kp - k))                   # padded centers invalid
-    return xp, wp, cp, cvp, kp
+            onehot = _onehot(a, w, bk, jj * bk)
+            sums_ref[jj * bk:(jj + 1) * bk, :] += _scatter(onehot, x)
+            cnt_ref[jj * bk:(jj + 1) * bk, :] += _rowsum(onehot)
+        cost_ref[...] += _rowsum(w * d2_scr[...])
 
 
 @functools.partial(jax.jit,
@@ -586,7 +437,7 @@ def fused_assign_reduce_chunked_pallas(
     if kp * (d + 1) * 4 > acc_budget:
         return _fused_assign_reduce_chunked_twopass(
             x, w, c, c_valid, interpret=interpret, bn=bn, bk=bk)
-    xp, wp, cp, cvp, kp = _pad_chunked(x, w, c, c_valid, bn, bk)
+    xp, wp, cp, cvp, kp = _pad_points(x, w, c, c_valid, bn, kp)
 
     np_ = xp.shape[0] // bn
     nc = kp // bk
@@ -595,25 +446,25 @@ def fused_assign_reduce_chunked_pallas(
         grid=(np_, nc),                              # chunk axis innermost
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, i)),
             pl.BlockSpec((bk, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bk,), lambda i, j: (j,)),
+            pl.BlockSpec((bk, 1), lambda i, j: (j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((kp, d), lambda i, j: (0, 0)),  # walk-resident
-            pl.BlockSpec((kp,), lambda i, j: (0,)),
+            pl.BlockSpec((kp, 1), lambda i, j: (0, 0)),
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((kp, d), jnp.float32),
-            jax.ShapeDtypeStruct((kp,), jnp.float32),
+            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bn,), jnp.float32),
-                        pltpu.VMEM((bn,), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32),
+                        pltpu.VMEM((1, bn), jnp.int32)],
         interpret=interpret,
     )(xp, wp, cp, cvp)
-    return sums[:k], counts[:k], cost[0, 0]
+    return sums[:k], counts[:k, 0], cost[0, 0]
 
 
 def _assign_chunked_kernel(x_ref, w_ref, c_ref, cv_ref,
@@ -630,20 +481,13 @@ def _assign_chunked_kernel(x_ref, w_ref, c_ref, cv_ref,
         d2_scr[...] = jnp.full(d2_scr.shape, _BIG, jnp.float32)
         idx_ref[...] = jnp.zeros(idx_ref.shape, jnp.int32)
 
+    # the running min stays in VMEM scratch; it is never written out
     x = x_ref[...].astype(jnp.float32)              # (bn, d) resident over j
-    local_min, local_arg = _panel_min(x, c_ref[...].astype(jnp.float32),
-                                      cv_ref[...])
-    local_arg = local_arg.astype(jnp.int32) + j * bk
-
-    prev = d2_scr[...]                              # running min stays in
-    better = local_min < prev                       # VMEM scratch; it is
-    idx_ref[...] = jnp.where(better, local_arg, idx_ref[...])  # never
-    d2_scr[...] = jnp.where(better, local_min, prev)           # written out
+    _running_min(x, c_ref, cv_ref, d2_scr, idx_ref, j * bk)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _cost():
-        w = w_ref[...].astype(jnp.float32)
-        cost_ref[0, 0] += jnp.sum(w * d2_scr[...])
+        cost_ref[...] += _rowsum(w_ref[...] * d2_scr[...])
 
 
 def _reduce_chunked_kernel(x_ref, w_ref, a_ref, sums_ref, cnt_ref,
@@ -656,18 +500,12 @@ def _reduce_chunked_kernel(x_ref, w_ref, a_ref, sums_ref, cnt_ref,
         sums_ref[...] = jnp.zeros(sums_ref.shape, jnp.float32)
         cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.float32)
 
+    # chunk-local assignment: points assigned outside [jc*bk, (jc+1)*bk)
+    # match no row of the chunk and produce an all-zero one-hot column
     x = x_ref[...].astype(jnp.float32)              # (bn, d)
-    w = w_ref[...].astype(jnp.float32)              # (bn,)
-    # chunk-local assignment: rows assigned outside [jc*bk, (jc+1)*bk)
-    # fall outside the iota range and produce an all-zero one-hot row
-    local = a_ref[...] - jc * bk
-    centers = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], bk), 1)
-    onehot = (local[:, None] == centers).astype(jnp.float32) * w[:, None]
-
-    sums_ref[...] += jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)          # (bk, d)
-    cnt_ref[...] += jnp.sum(onehot, axis=0)
+    onehot = _onehot(a_ref[...], w_ref[...], bk, jc * bk)
+    sums_ref[...] += _scatter(onehot, x)            # (bk, d)
+    cnt_ref[...] += _rowsum(onehot)
 
 
 def _fused_assign_reduce_chunked_twopass(
@@ -681,7 +519,8 @@ def _fused_assign_reduce_chunked_twopass(
     center chunk to scatter into each chunk's resident accumulator."""
     n, d = x.shape
     k = c.shape[0]
-    xp, wp, cp, cvp, kp = _pad_chunked(x, w, c, c_valid, bn, bk)
+    xp, wp, cp, cvp, kp = _pad_points(x, w, c, c_valid, bn,
+                                      -(-k // bk) * bk)
 
     np_ = xp.shape[0] // bn
     nc = kp // bk
@@ -690,19 +529,19 @@ def _fused_assign_reduce_chunked_twopass(
         grid=(np_, nc),                              # chunk axis innermost
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, i)),
             pl.BlockSpec((bk, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bk,), lambda i, j: (j,)),
+            pl.BlockSpec((bk, 1), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, i)),
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((xp.shape[0],), jnp.int32),
+            jax.ShapeDtypeStruct(wp.shape, jnp.int32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bn,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32)],
         interpret=interpret,
     )(xp, wp, cp, cvp)
 
@@ -711,20 +550,20 @@ def _fused_assign_reduce_chunked_twopass(
         grid=(nc, np_),                              # panel axis innermost
         in_specs=[
             pl.BlockSpec((bn, d), lambda jc, i: (i, 0)),
-            pl.BlockSpec((bn,), lambda jc, i: (i,)),
-            pl.BlockSpec((bn,), lambda jc, i: (i,)),
+            pl.BlockSpec((1, bn), lambda jc, i: (0, i)),
+            pl.BlockSpec((1, bn), lambda jc, i: (0, i)),
         ],
         out_specs=[
             pl.BlockSpec((bk, d), lambda jc, i: (jc, 0)),
-            pl.BlockSpec((bk,), lambda jc, i: (jc,)),
+            pl.BlockSpec((bk, 1), lambda jc, i: (jc, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((kp, d), jnp.float32),
-            jax.ShapeDtypeStruct((kp,), jnp.float32),
+            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(xp, wp, assign)
-    return sums[:k], counts[:k], cost[0, 0]
+    return sums[:k], counts[:k, 0], cost[0, 0]
 
 
 def _remove_chunked_kernel(x_ref, a_ref, c_ref, cv_ref, v_ref,
@@ -741,15 +580,15 @@ def _remove_chunked_kernel(x_ref, a_ref, c_ref, cv_ref, v_ref,
         d2_scr[...] = jnp.full(d2_scr.shape, _BIG, jnp.float32)
 
     x = x_ref[0].astype(jnp.float32)                 # (bn, d) resident over jc
-    local_min, _ = _panel_min(x, c_ref[...].astype(jnp.float32),
-                              cv_ref[...])
+    local_min = _panel_dmin(x, c_ref[...].astype(jnp.float32), cv_ref[...])
     d2_scr[...] = jnp.minimum(d2_scr[...], local_min)  # running min in VMEM
 
     @pl.when(jc == pl.num_programs(2) - 1)
     def _finish_panel():
-        keep = (a_ref[0] != 0) & (d2_scr[...] > v_ref[0, 0])
-        out_ref[0] = keep.astype(jnp.int8)
-        live_ref[0] += jnp.sum(keep.astype(jnp.int32))
+        keep = ((a_ref[0] != 0) & (d2_scr[...] > v_ref[...])
+                ).astype(jnp.int32)
+        out_ref[0] = keep
+        live_ref[0] += _rowsum(keep)
 
 
 @functools.partial(jax.jit,
@@ -770,20 +609,11 @@ def remove_below_chunked_pallas(x: jax.Array, c: jax.Array,
     """
     m, p, d = x.shape
     k = c.shape[0]
-    if c_valid is None:
-        c_valid = jnp.ones((k,), jnp.int8)
-    else:
-        c_valid = c_valid.astype(jnp.int8)
-
     t_bn, t_bk = chunk_sizes(d, str(x.dtype))
     bn = clamp_bn(t_bn if bn is None else bn, p)
     bk = t_bk if k_chunk is None else k_chunk
     kp = -(-k // bk) * bk
-    xp = jnp.pad(x, ((0, 0), (0, -p % bn), (0, 0)))
-    ap = jnp.pad(alive.astype(jnp.int8), ((0, 0), (0, -p % bn)))  # pad = dead
-    cp = jnp.pad(c, ((0, kp - k), (0, 0)))
-    cvp = jnp.pad(c_valid, (0, kp - k))
-    vv = jnp.reshape(v, (1, 1)).astype(jnp.float32)
+    xp, ap, cp, cvp = _pad_machines(x, c, alive, c_valid, bn, kp)
 
     grid = (m, xp.shape[1] // bn, kp // bk)          # chunk axis innermost
     alive_new, live = pl.pallas_call(
@@ -791,20 +621,20 @@ def remove_below_chunked_pallas(x: jax.Array, c: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bn, d), lambda i, j, jc: (i, j, 0)),
-            pl.BlockSpec((1, bn), lambda i, j, jc: (i, j)),
+            pl.BlockSpec((1, 1, bn), lambda i, j, jc: (i, 0, j)),
             pl.BlockSpec((bk, d), lambda i, j, jc: (jc, 0)),
-            pl.BlockSpec((bk,), lambda i, j, jc: (jc,)),
+            pl.BlockSpec((bk, 1), lambda i, j, jc: (jc, 0)),
             pl.BlockSpec((1, 1), lambda i, j, jc: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bn), lambda i, j, jc: (i, j)),
-            pl.BlockSpec((1,), lambda i, j, jc: (i,)),
+            pl.BlockSpec((1, 1, bn), lambda i, j, jc: (i, 0, j)),
+            pl.BlockSpec((1, 1, 1), lambda i, j, jc: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m, xp.shape[1]), jnp.int8),
-            jax.ShapeDtypeStruct((m,), jnp.int32),
+            jax.ShapeDtypeStruct(ap.shape, jnp.int32),
+            jax.ShapeDtypeStruct((m, 1, 1), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((bn,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32)],
         interpret=interpret,
-    )(xp, ap, cp, cvp, vv)
-    return alive_new[:, :p].astype(bool), live
+    )(xp, ap, cp, cvp, _scalar(v))
+    return alive_new[:, 0, :p].astype(bool), live[:, 0, 0]

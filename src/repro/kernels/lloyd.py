@@ -16,10 +16,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.fused_lloyd import _onehot, _row, _rowsum, _scatter
 from repro.kernels.tuning import block_sizes, clamp_bn
 
 
-def _lloyd_kernel(x_ref, w_ref, a_ref, sums_ref, cnt_ref, *, k: int):
+def _lloyd_kernel(x_ref, w_ref, a_ref, sums_ref, cnt_ref):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -28,16 +29,9 @@ def _lloyd_kernel(x_ref, w_ref, a_ref, sums_ref, cnt_ref, *, k: int):
         cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.float32)
 
     x = x_ref[...].astype(jnp.float32)              # (bn, d)
-    w = w_ref[...].astype(jnp.float32)              # (bn,)
-    a = a_ref[...]                                  # (bn,) int32
-
-    centers = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], k), 1)
-    onehot = (a[:, None] == centers).astype(jnp.float32) * w[:, None]
-
-    sums_ref[...] += jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)          # (k, d)
-    cnt_ref[...] += jnp.sum(onehot, axis=0)
+    onehot = _onehot(a_ref[...], w_ref[...], sums_ref.shape[0])  # (kp, bn)
+    sums_ref[...] += _scatter(onehot, x)             # (kp, d)
+    cnt_ref[...] += _rowsum(onehot)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret", "bn"))
@@ -49,28 +43,29 @@ def lloyd_reduce_pallas(x: jax.Array, w: jax.Array, assign: jax.Array,
     if bn is None:
         bn, _ = block_sizes(d, k, str(x.dtype))   # shared autotune table
     bn = clamp_bn(bn, n)
-    n_pad = -n % bn
-    xp = jnp.pad(x, ((0, n_pad), (0, 0)))
-    wp = jnp.pad(w, (0, n_pad))                      # pad weight 0 -> no-op rows
-    ap = jnp.pad(assign, (0, n_pad))
+    n_pad = n + (-n % bn)
+    kp = -(-k // 128) * 128
+    xp = jnp.pad(x, ((0, n_pad - n), (0, 0)))
+    wp = _row(w, n_pad)                              # pad weight 0 -> no-op rows
+    ap = _row(assign, n_pad, jnp.int32)
 
-    grid = (xp.shape[0] // bn,)
+    grid = (n_pad // bn,)
     sums, counts = pl.pallas_call(
-        functools.partial(_lloyd_kernel, k=k),
+        _lloyd_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((k, d), lambda i: (0, 0)),
-            pl.BlockSpec((k,), lambda i: (0,)),
+            pl.BlockSpec((kp, d), lambda i: (0, 0)),
+            pl.BlockSpec((kp, 1), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((k, d), jnp.float32),
-            jax.ShapeDtypeStruct((k,), jnp.float32),
+            jax.ShapeDtypeStruct((kp, d), jnp.float32),
+            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(xp, wp, ap)
-    return sums, counts
+    return sums[:k], counts[:k, 0]

@@ -22,10 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.fused_lloyd import _BIG, _pad_points, _running_min
 from repro.kernels.tuning import block_sizes, clamp_bn
-
-
-_BIG = 3.0e38  # plain float so the kernel captures no traced constants
 
 
 def _min_dist_kernel(x_ref, c_ref, cv_ref, d2_ref, idx_ref, *, bk: int):
@@ -36,25 +34,10 @@ def _min_dist_kernel(x_ref, c_ref, cv_ref, d2_ref, idx_ref, *, bk: int):
         d2_ref[...] = jnp.full(d2_ref.shape, _BIG, jnp.float32)
         idx_ref[...] = jnp.zeros(idx_ref.shape, jnp.int32)
 
+    # ||x||^2 - 2 x.c + ||c||^2 with the cross term on the MXU; the
+    # running (min, argmin) rows live in the resident output blocks
     x = x_ref[...].astype(jnp.float32)            # (bn, d)
-    c = c_ref[...].astype(jnp.float32)            # (bk, d)
-    cv = cv_ref[...]                              # (bk,) bool as int8
-
-    # ||x||^2 - 2 x.c + ||c||^2 ; cross term on the MXU.
-    dots = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    x2 = jnp.sum(x * x, axis=1, keepdims=True)    # (bn, 1)
-    c2 = jnp.sum(c * c, axis=1)[None, :]          # (1, bk)
-    d2 = x2 - 2.0 * dots + c2                     # (bn, bk)
-    d2 = jnp.where(cv[None, :] != 0, d2, _BIG)
-
-    local_min = jnp.min(d2, axis=1)
-    local_arg = jnp.argmin(d2, axis=1).astype(jnp.int32) + j * bk
-
-    prev_min = d2_ref[...]
-    better = local_min < prev_min
-    idx_ref[...] = jnp.where(better, local_arg, idx_ref[...])
-    d2_ref[...] = jnp.where(better, local_min, prev_min)
+    _running_min(x, c_ref, cv_ref, d2_ref, idx_ref, j * bk)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bn", "bk"))
@@ -70,37 +53,29 @@ def min_dist_pallas(x: jax.Array, c: jax.Array,
     """
     n, d = x.shape
     k = c.shape[0]
-    if c_valid is None:
-        c_valid = jnp.ones((k,), jnp.int8)
-    else:
-        c_valid = c_valid.astype(jnp.int8)
-
     t_bn, t_bk = block_sizes(d, k, str(x.dtype))  # shared autotune table
     bn = clamp_bn(t_bn if bn is None else bn, n)
     bk = clamp_bn(t_bk if bk is None else bk, k)
-    n_pad = -n % bn
-    k_pad = -k % bk
-    xp = jnp.pad(x, ((0, n_pad), (0, 0)))
-    cp = jnp.pad(c, ((0, k_pad), (0, 0)))
-    cvp = jnp.pad(c_valid, (0, k_pad))  # padded centers invalid
+    xp, _, cp, cvp, kp = _pad_points(x, None, c, c_valid, bn,
+                                     -(-k // bk) * bk)
 
-    grid = (xp.shape[0] // bn, cp.shape[0] // bk)
+    grid = (xp.shape[0] // bn, kp // bk)
     d2, idx = pl.pallas_call(
         functools.partial(_min_dist_kernel, bk=bk),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bk, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bk,), lambda i, j: (j,)),
+            pl.BlockSpec((bk, 1), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, i)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((xp.shape[0],), jnp.float32),
-            jax.ShapeDtypeStruct((xp.shape[0],), jnp.int32),
+            jax.ShapeDtypeStruct((1, xp.shape[0]), jnp.float32),
+            jax.ShapeDtypeStruct((1, xp.shape[0]), jnp.int32),
         ],
         interpret=interpret,
     )(xp, cp, cvp)
-    return jnp.maximum(d2[:n], 0.0), idx[:n]
+    return d2[0, :n], idx[0, :n]

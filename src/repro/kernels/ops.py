@@ -58,11 +58,9 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.fused_lloyd import (fused_assign_reduce_chunked_pallas,
                                        fused_assign_reduce_pallas,
-                                       fused_assign_reduce_pipelined_pallas,
                                        remove_below_chunked_pallas,
                                        remove_below_pallas,
-                                       update_min_dist_pallas,
-                                       update_min_dist_pipelined_pallas)
+                                       update_min_dist_pallas)
 from repro.kernels.lloyd import lloyd_reduce_pallas
 from repro.kernels.min_dist import min_dist_pallas
 from repro.kernels.sensitivity import sensitivity_scores_pallas
@@ -71,10 +69,6 @@ from repro.kernels.truncated import truncated_cost_pallas
 _MAX_PALLAS_D = 512   # larger feature dims fall back to the XLA path
 _MAX_PALLAS_K = 1024  # fused kernels keep all centers in VMEM up to this;
                       # beyond it the chunked-K Pallas variants take over
-_PIPELINE_MIN_N = 32768  # walks this long switch to the double-buffered
-                         # DMA variants (explicit HBM->VMEM prefetch); the
-                         # threshold is static per jit cache entry, so the
-                         # dispatch costs nothing at run time
 
 # The public kernel surface; the conformance harness iterates over this.
 ENTRY_POINTS = ("min_dist", "lloyd_reduce", "fused_assign_reduce",
@@ -82,10 +76,16 @@ ENTRY_POINTS = ("min_dist", "lloyd_reduce", "fused_assign_reduce",
                 "truncated_cost")
 
 
+def on_tpu() -> bool:
+    """Whether kernels compile for the chip (else Pallas interprets them
+    and ``auto`` takes the oracle)."""
+    return jax.default_backend() == "tpu"
+
+
 def _backend(explicit: Optional[str]) -> str:
     choice = explicit or os.environ.get("REPRO_KERNEL_BACKEND") or "auto"
     if choice == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "ref"
+        return "pallas" if on_tpu() else "ref"
     if choice not in ("ref", "pallas"):
         raise ValueError(
             f"unknown kernel backend {choice!r} (from "
@@ -101,7 +101,7 @@ def min_dist(x: jax.Array, c: jax.Array,
     """(n,) min squared distance to valid centers and (n,) argmin."""
     b = _backend(backend)
     if b == "pallas" and x.shape[-1] <= _MAX_PALLAS_D:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
         return min_dist_pallas(x, c, c_valid, interpret=interpret)
     return ref.min_dist_ref(x, c, c_valid)
 
@@ -112,7 +112,7 @@ def lloyd_reduce(x: jax.Array, w: jax.Array, assign: jax.Array, k: int,
     """Weighted per-center (sums, counts) for a Lloyd step."""
     b = _backend(backend)
     if b == "pallas" and x.shape[-1] <= _MAX_PALLAS_D:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
         return lloyd_reduce_pallas(x, w, assign, k, interpret=interpret)
     return ref.lloyd_reduce_ref(x, w, assign, k)
 
@@ -125,20 +125,15 @@ def fused_assign_reduce(x: jax.Array, w: jax.Array, c: jax.Array,
 
     Semantics == min_dist followed by lloyd_reduce plus the weighted cost
     of ``c`` on (x, w); every Pallas path reads ``x`` from HBM exactly
-    once. Walks beyond ``_PIPELINE_MIN_N`` points run the double-buffered
-    DMA variant (panel i+1's HBM->VMEM copy in flight while panel i
-    computes). Center sets beyond ``_MAX_PALLAS_K`` run the chunked-K
+    once. Center sets beyond ``_MAX_PALLAS_K`` run the chunked-K
     kernel — a SINGLE grid walk with walk-resident (kp, d) accumulators
     and a per-chunk scatter once each panel's argmin is final (see
     ``benchmarks/bench_kernels.analytic`` for the byte model).
     """
     b = _backend(backend)
     if b == "pallas" and x.shape[-1] <= _MAX_PALLAS_D:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
         if c.shape[0] <= _MAX_PALLAS_K:
-            if x.shape[0] >= _PIPELINE_MIN_N:
-                return fused_assign_reduce_pipelined_pallas(
-                    x, w, c, c_valid, interpret=interpret)
             return fused_assign_reduce_pallas(x, w, c, c_valid,
                                               interpret=interpret)
         return fused_assign_reduce_chunked_pallas(x, w, c, c_valid,
@@ -153,7 +148,7 @@ def remove_below(x: jax.Array, c: jax.Array, alive: jax.Array, v: jax.Array,
     """Fused SOCCER removal: ((m, p) bool alive & min-d2 > v, (m,) counts)."""
     b = _backend(backend)
     if b == "pallas" and x.shape[-1] <= _MAX_PALLAS_D:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
         if c.shape[0] <= _MAX_PALLAS_K:
             return remove_below_pallas(x, c, alive, v, c_valid,
                                        interpret=interpret)
@@ -175,23 +170,19 @@ def update_min_dist(x: jax.Array, w: jax.Array, c: jax.Array,
     beyond ``_MAX_PALLAS_K`` (k-means‖ seeding at large k_plus: the
     per-round buffer is ~6·k rows) run as a static sequence of resident
     sweeps — the elementwise min is associative, so slicing the block is
-    exact, and the path stays on Pallas. Walks beyond ``_PIPELINE_MIN_N``
-    points double-buffer both the input stream and the (n,) output
-    write-back with explicit DMA.
+    exact, and the path stays on Pallas.
     """
     b = _backend(backend)
     if b == "pallas" and x.shape[-1] <= _MAX_PALLAS_D:
-        interpret = jax.default_backend() != "tpu"
-        kernel = (update_min_dist_pipelined_pallas
-                  if x.shape[0] >= _PIPELINE_MIN_N else
-                  update_min_dist_pallas)
+        interpret = not on_tpu()
         kc = c.shape[0]
         if kc <= _MAX_PALLAS_K:
-            return kernel(x, w, c, d2, c_valid, interpret=interpret)
+            return update_min_dist_pallas(x, w, c, d2, c_valid,
+                                          interpret=interpret)
         for s in range(0, kc, _MAX_PALLAS_K):
             cv = None if c_valid is None else c_valid[s:s + _MAX_PALLAS_K]
-            d2, mass = kernel(x, w, c[s:s + _MAX_PALLAS_K],
-                              d2, cv, interpret=interpret)
+            d2, mass = update_min_dist_pallas(
+                x, w, c[s:s + _MAX_PALLAS_K], d2, cv, interpret=interpret)
         return d2, mass
     return ref.update_min_dist_ref(x, w, c, d2, c_valid)
 
@@ -216,7 +207,7 @@ def sensitivity_scores(x: jax.Array, w: jax.Array, c: jax.Array,
     """
     b = _backend(backend)
     if b == "pallas" and x.shape[-1] <= _MAX_PALLAS_D:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
         if c.shape[0] <= _MAX_PALLAS_K:
             return sensitivity_scores_pallas(x, w, c, c_valid,
                                              interpret=interpret)
@@ -246,7 +237,7 @@ def truncated_cost(x: jax.Array, w: jax.Array, c: jax.Array, v: jax.Array,
     """
     b = _backend(backend)
     if b == "pallas" and x.shape[-1] <= _MAX_PALLAS_D:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
         if c.shape[0] <= _MAX_PALLAS_K:
             return truncated_cost_pallas(x, w, c, v, c_valid,
                                          interpret=interpret)

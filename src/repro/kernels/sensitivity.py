@@ -38,13 +38,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.fused_lloyd import _panel_min
-from repro.kernels.tuning import block_sizes, clamp_bn
+from repro.kernels.fused_lloyd import (_onehot, _pad_points, _panel_min,
+                                       _resident_bn, _rowsum)
 
 
 def _sensitivity_kernel(x_ref, w_ref, c_ref, cv_ref,
-                        scores_ref, assign_ref, mass_ref, cost_ref,
-                        *, kp: int):
+                        scores_ref, assign_ref, mass_ref, cost_ref):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -53,19 +52,15 @@ def _sensitivity_kernel(x_ref, w_ref, c_ref, cv_ref,
         cost_ref[...] = jnp.zeros(cost_ref.shape, jnp.float32)
 
     x = x_ref[...].astype(jnp.float32)              # (bn, d)
-    w = w_ref[...].astype(jnp.float32)              # (bn,)
+    w = w_ref[...]                                  # (1, bn)
     c = c_ref[...].astype(jnp.float32)              # (kp, d)
     dmin, a = _panel_min(x, c, cv_ref[...])
 
     s = w * dmin
     scores_ref[...] = s
-    assign_ref[...] = a.astype(jnp.int32)
-
-    centers = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], kp), 1)
-    onehot = (a.astype(jnp.int32)[:, None] == centers
-              ).astype(jnp.float32) * w[:, None]    # (bn, kp)
-    mass_ref[...] += jnp.sum(onehot, axis=0)
-    cost_ref[0, 0] += jnp.sum(s)
+    assign_ref[...] = a
+    mass_ref[...] += _rowsum(_onehot(a, w, c.shape[0]))   # (kp, 1)
+    cost_ref[...] += _rowsum(s)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bn"))
@@ -79,44 +74,31 @@ def sensitivity_scores_pallas(x: jax.Array, w: jax.Array, c: jax.Array,
     () cost). Semantics == ``kernels.ref.sensitivity_scores_ref``."""
     n, d = x.shape
     k = c.shape[0]
-    if c_valid is None:
-        c_valid = jnp.ones((k,), jnp.int8)
-    else:
-        c_valid = c_valid.astype(jnp.int8)
-
-    kp = -(-k // 128) * 128                          # centers stay resident
-    if bn is None:
-        bn, _ = block_sizes(d, k, str(x.dtype))
-        if kp >= 512:                                # keep the (bn, kp) one-hot
-            bn = min(bn, 256)                        # inside the VMEM budget
-    bn = clamp_bn(bn, n)
-    xp = jnp.pad(x, ((0, -n % bn), (0, 0)))
-    wp = jnp.pad(w, (0, -n % bn))                    # weight-0 rows are no-ops
-    cp = jnp.pad(c, ((0, kp - k), (0, 0)))
-    cvp = jnp.pad(c_valid, (0, kp - k))              # padded centers invalid
+    bn = _resident_bn(d, k, n, x.dtype, bn)          # centers stay resident
+    xp, wp, cp, cvp, kp = _pad_points(x, w, c, c_valid, bn)
 
     grid = (xp.shape[0] // bn,)
     scores, assign, mass, cost = pl.pallas_call(
-        functools.partial(_sensitivity_kernel, kp=kp),
+        _sensitivity_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
             pl.BlockSpec((kp, d), lambda i: (0, 0)),
-            pl.BlockSpec((kp,), lambda i: (0,)),
+            pl.BlockSpec((kp, 1), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((kp,), lambda i: (0,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((kp, 1), lambda i: (0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((xp.shape[0],), jnp.float32),
-            jax.ShapeDtypeStruct((xp.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((kp,), jnp.float32),
+            jax.ShapeDtypeStruct(wp.shape, jnp.float32),
+            jax.ShapeDtypeStruct(wp.shape, jnp.int32),
+            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
     )(xp, wp, cp, cvp)
-    return scores[:n], assign[:n], mass[:k], cost[0, 0]
+    return scores[0, :n], assign[0, :n], mass[:k, 0], cost[0, 0]

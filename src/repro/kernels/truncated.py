@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.fused_lloyd import _panel_min
+from repro.kernels.fused_lloyd import (_pad_points, _panel_dmin, _rowsum,
+                                       _scalar)
 from repro.kernels.tuning import block_sizes, clamp_bn
 
 
@@ -54,15 +55,15 @@ def _truncated_kernel(x_ref, w_ref, c_ref, cv_ref, v_ref,
         tcost_ref[...] = jnp.zeros(tcost_ref.shape, jnp.float32)
 
     x = x_ref[...].astype(jnp.float32)              # (bn, d)
-    w = w_ref[...].astype(jnp.float32)              # (bn,)
+    w = w_ref[...]                                  # (1, bn)
     c = c_ref[...].astype(jnp.float32)              # (kp, d)
-    dmin, _ = _panel_min(x, c, cv_ref[...])
+    dmin = _panel_dmin(x, c, cv_ref[...])
 
-    below = dmin <= v_ref[0, 0]
+    below = dmin <= v_ref[...]
     s = jnp.where(w > 0, w * dmin, 0.0)             # padded rows: no side
-    kept_ref[0, 0] += jnp.sum(jnp.where(below, s, 0.0))
-    tmass_ref[0, 0] += jnp.sum(jnp.where(below, 0.0, w))
-    tcost_ref[0, 0] += jnp.sum(jnp.where(below, 0.0, s))
+    kept_ref[...] += _rowsum(jnp.where(below, s, 0.0))
+    tmass_ref[...] += _rowsum(jnp.where(below, 0.0, w))
+    tcost_ref[...] += _rowsum(jnp.where(below, 0.0, s))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bn"))
@@ -76,42 +77,26 @@ def truncated_cost_pallas(x: jax.Array, w: jax.Array, c: jax.Array,
     () tail_cost). Semantics == ``kernels.ref.truncated_cost_ref``."""
     n, d = x.shape
     k = c.shape[0]
-    if c_valid is None:
-        c_valid = jnp.ones((k,), jnp.int8)
-    else:
-        c_valid = c_valid.astype(jnp.int8)
-
-    kp = -(-k // 128) * 128                          # centers stay resident
     if bn is None:
         bn, _ = block_sizes(d, k, str(x.dtype))
     bn = clamp_bn(bn, n)
-    xp = jnp.pad(x, ((0, -n % bn), (0, 0)))
-    wp = jnp.pad(w, (0, -n % bn))                    # weight-0 rows: no side
-    cp = jnp.pad(c, ((0, kp - k), (0, 0)))
-    cvp = jnp.pad(c_valid, (0, kp - k))              # padded centers invalid
-    vv = jnp.reshape(v, (1, 1)).astype(jnp.float32)
+    xp, wp, cp, cvp, kp = _pad_points(x, w, c, c_valid, bn)  # centers
+    # stay resident; weight-0 padding rows land on neither side
 
     grid = (xp.shape[0] // bn,)
+    scalar = pl.BlockSpec((1, 1), lambda i: (0, 0))
     kept, tmass, tcost = pl.pallas_call(
         _truncated_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
             pl.BlockSpec((kp, d), lambda i: (0, 0)),
-            pl.BlockSpec((kp,), lambda i: (0,)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((kp, 1), lambda i: (0, 0)),
+            scalar,
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
+        out_specs=[scalar, scalar, scalar],
+        out_shape=[jax.ShapeDtypeStruct((1, 1), jnp.float32)] * 3,
         interpret=interpret,
-    )(xp, wp, cp, cvp, vv)
+    )(xp, wp, cp, cvp, _scalar(v))
     return kept[0, 0], tmass[0, 0], tcost[0, 0]

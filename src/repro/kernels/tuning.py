@@ -10,15 +10,16 @@ spot). Lookup order per query:
 1. **Measured table** — winners of the timed sweep in
    ``repro.kernels.autotune`` (``python -m repro.kernels.autotune``,
    ``make autotune``), persisted per JAX backend as JSON:
-   ``~/.cache/repro/tuned_<backend>.json`` (user override, written by the
-   CLI by default) first, then the committed package table
-   ``kernels/tuned/<backend>.json``. Gated by ``REPRO_AUTOTUNE``:
+   ``<checkout>/.cache/repro/tuned_<backend>.json`` (local override,
+   written by the CLI by default; ``REPRO_CACHE_DIR`` moves it) first,
+   then the committed package table ``kernels/tuned/<backend>.json``.
+   Gated by ``REPRO_AUTOTUNE``:
 
    * ``cached`` (default) — consult the persisted tables, fall back to
      the analytic model on a miss;
    * ``off``    — analytic model only (the pre-autotune behavior);
    * ``force``  — on a miss, run the quick measured sweep for this
-     backend right now, cache it under ``~/.cache/repro`` and use it.
+     backend right now, cache it as the local override and use it.
 
 2. **Analytic model** — the static tables below: values chosen so the
    resident f32 panels — x (bn, d), centers (bk, d), the (bn, bk)
@@ -38,13 +39,19 @@ import collections
 import json
 import os
 import pathlib
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
+
+from repro.launch.cache import CHECKOUT
 
 # Measured-table lookup outcomes ("measured_hit" / "measured_miss" /
 # "analytic_only"), surfaced through the metrics registry as
 # ``kernels.tuning.autotune`` (repro.obs.metrics). Counted at kernel
 # *trace* time — a warm jit cache adds nothing, which is itself signal.
 TUNE_COUNTS = collections.Counter()
+
+# Every table file a lookup actually loaded, in load order — so a run can
+# show which committed or local tables its block sizes rest on.
+TABLES_READ: List[str] = []
 
 _D_BUCKETS = (128, 256, 512)
 _K_BUCKETS = (128, 256, 1024)
@@ -112,10 +119,9 @@ def package_table_path(backend: str) -> pathlib.Path:
 
 
 def cache_table_path(backend: str) -> pathlib.Path:
-    """The user-cache override (written by the autotune CLI by default)."""
-    root = os.environ.get("REPRO_CACHE_DIR",
-                          os.path.join(os.path.expanduser("~"), ".cache",
-                                       "repro"))
+    """The local override inside the checkout (written by the autotune
+    CLI by default)."""
+    root = os.environ.get("REPRO_CACHE_DIR") or CHECKOUT / ".cache" / "repro"
     return pathlib.Path(root) / f"tuned_{backend}.json"
 
 
@@ -136,7 +142,7 @@ def _load_measured(backend: str) -> Dict[str, dict]:
     if backend in _MEASURED_CACHE:
         return _MEASURED_CACHE[backend]
     table: Dict[str, dict] = {}
-    # package table first so the user cache overrides it
+    # package table first so the local override wins
     for path in (package_table_path(backend), cache_table_path(backend)):
         try:
             payload = json.loads(path.read_text())
@@ -144,6 +150,7 @@ def _load_measured(backend: str) -> Dict[str, dict]:
             continue
         if payload.get("backend", backend) != backend:
             continue
+        TABLES_READ.append(str(path))
         table.update(payload.get("entries", {}))
     _MEASURED_CACHE[backend] = table
     return table

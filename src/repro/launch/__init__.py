@@ -10,8 +10,7 @@ runs this module BEFORE ``__main__`` gets to set
 the flag at first import — so nothing here may import jax eagerly.
 """
 _MESH_EXPORTS = ("fsdp_axes", "initialize_multi_host", "machine_mesh",
-                 "make_mesh_compat", "make_production_mesh",
-                 "make_test_mesh")
+                 "make_production_mesh", "make_test_mesh")
 
 __all__ = list(_MESH_EXPORTS)
 

@@ -90,6 +90,8 @@ def main(argv=None) -> int:
     initialize_multi_host(coordinator_address=args.coordinator,
                           num_processes=args.num_processes,
                           process_id=args.process_id)
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
 
     import jax
     import numpy as np

@@ -9,34 +9,19 @@ import; tests/benches see the single real device.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-
-def make_mesh_compat(shape, axes) -> Mesh:
-    """``jax.make_mesh`` across jax versions.
-
-    ``jax.sharding.AxisType`` (and ``make_mesh``'s ``axis_types``
-    parameter) only exist from jax 0.5; on older jax every axis is
-    implicitly auto-sharded, which is exactly the ``AxisType.Auto`` we
-    request on newer versions — so both branches build the same mesh.
-    """
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(n_data: int = 1, n_model: int = 1) -> Mesh:
     """Small mesh over however many (host) devices a test process has."""
-    return make_mesh_compat((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def fsdp_axes(mesh: Mesh):
@@ -81,4 +66,4 @@ def machine_mesh(m=None) -> Mesh:
             f"cluster has {n} global devices "
             f"({jax.process_count()} process(es) x "
             f"{jax.local_device_count()} local)")
-    return make_mesh_compat((m,), ("machines",))
+    return jax.make_mesh((m,), ("machines",), axis_types=(AxisType.Auto,))

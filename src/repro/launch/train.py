@@ -18,13 +18,12 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import get_config
 from repro.launch.input_specs import make_partitioner, opt_shardings
-from repro.launch.mesh import make_mesh_compat
 from repro.sharding.activations import activation_mesh
 from repro.train.optimizer import OptConfig
 from repro.train.train_step import make_train_state, make_train_step
@@ -34,7 +33,8 @@ def build_mesh(spec: str):
     shape = tuple(int(s) for s in spec.split(","))
     names = ("data", "model")[: len(shape)] if len(shape) <= 2 else \
         ("pod", "data", "model")
-    return make_mesh_compat(shape, names)
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def main():

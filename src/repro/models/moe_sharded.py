@@ -160,13 +160,7 @@ def moe_apply_sharded(p, cfg, x, *, capacity_factor: float
     manual = set(data_axes) | ({tp} if tp else set())
     in_specs = (p_specs, P(data_axes, None, None))
     out_specs = (P(data_axes, None, None), P())
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, axis_names=manual,
-                           check_vma=False)
-    else:  # jax < 0.5: axes not listed in `auto` are manual
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False,
-                       auto=frozenset(mesh.axis_names) - manual)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, axis_names=manual,
+                       check_vma=False)
     return fn(p, x)

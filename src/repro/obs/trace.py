@@ -17,8 +17,7 @@ Design constraints (the tentpole contract):
 * **Optional ``jax.profiler.TraceAnnotation`` passthrough.** In
   ``mode="full"`` with ``annotate=True``, each span also opens a
   profiler annotation so the repo's spans line up with XLA's own
-  timeline in a captured profile. jax is imported lazily and failures
-  are swallowed — the tracer works in a jax-free interpreter.
+  timeline in a captured profile. jax is imported lazily, only then.
 
 The per-round record schema is pinned (``ROUND_SCHEMA``): field names
 and value types are part of the exported JSONL contract and covered by a
@@ -129,12 +128,9 @@ class Span:
     def __enter__(self) -> "Span":
         _STATS["spans"] += 1
         if self._trace.annotate:
-            try:  # pragma: no cover - depends on the jax build
-                from jax.profiler import TraceAnnotation
-                self._annotation = TraceAnnotation(self.name)
-                self._annotation.__enter__()
-            except Exception:
-                self._annotation = None
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation(self.name)
+            self._annotation.__enter__()
         self.t0 = clock()
         return self
 
@@ -305,10 +301,11 @@ def timed_compile(fn, *args):
 
     Returns ``(callable, compile_s)``. On success the callable is the
     compiled executable — later calls pay zero trace/compile — and
-    ``compile_s`` is the measured trace+compile wall. Anything without a
-    working ``.lower`` (stubs, exotic backends) falls back to ``(fn,
-    None)``: the first call will compile inline and its round wall will
-    absorb the cost, exactly the untraced behavior.
+    ``compile_s`` is the measured trace+compile wall. A callable without
+    ``.lower`` (a plain-function stub) falls back to ``(fn, None)``: the
+    first call will compile inline and its round wall will absorb the
+    cost, exactly the untraced behavior. A compile error is raised here,
+    where it happens.
 
     NOTE for callers recording wire bytes: jax traces ``fn`` *here*, so
     the call must happen inside the same ``wire_tally`` context the
@@ -316,7 +313,8 @@ def timed_compile(fn, *args):
     """
     t0 = clock()
     try:
-        compiled = fn.lower(*args).compile()
-    except Exception:
+        lower = fn.lower
+    except AttributeError:
         return fn, None
+    compiled = lower(*args).compile()
     return compiled, clock() - t0

@@ -13,6 +13,7 @@ import argparse
 import sys
 import time
 
+from repro.launch.cache import use_compile_cache
 from repro.scenarios import library  # noqa: F401  (registers scenarios)
 from repro.scenarios.registry import get_scenario, list_scenarios
 from repro.scenarios.report import (format_table, summarize_gap,
@@ -44,6 +45,7 @@ def main(argv=None) -> int:
     ap.add_argument("--list", action="store_true",
                     help="list registered scenarios and exit")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.list:
         for name in list_scenarios():

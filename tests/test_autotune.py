@@ -81,7 +81,7 @@ def test_autotune_off_ignores_table(tuned_env, monkeypatch):
 
 
 def test_user_cache_overrides_package_table(tuned_env, monkeypatch):
-    """The ~/.cache table must shadow the committed package table."""
+    """The local override table must shadow the committed package table."""
     import jax
     backend = jax.default_backend()
     key = tuning.measured_key("block", 64, 64, "float32")

@@ -285,43 +285,42 @@ def test_all_zero_weights(name, n, d, k):
     assert float(kc) == 0.0 and float(tm) == 0.0 and float(tc) == 0.0
 
 
-# ---- pipelined / single-walk variants ---------------------------------
+# ---- multi-panel walks / single-walk variants --------------------------
 
-# (name, n, d, k): multi-panel walks so the double-buffered DMA pattern
-# actually rotates slots (bn=128 forced -> ceil(n/128) panels)
-PIPELINED_SHAPES = [
+# (name, n, d, k): walks over several point panels (bn=128 forced ->
+# ceil(n/128) panels), so the per-panel row blocks and the walk-resident
+# accumulators are exercised across panel boundaries
+MULTI_PANEL_SHAPES = [
     ("multi_panel", 2600, 16, 32),        # 21 panels, odd tail
-    ("two_panels", 256, 8, 5),            # exactly 2 panels = 2 slots
-    ("one_panel", 100, 8, 5),             # degenerate: prefetch never fires
+    ("two_panels", 256, 8, 5),            # exactly 2 panels
+    ("one_panel", 100, 8, 5),             # degenerate: one padded panel
 ]
-PIPE_IDS = [s[0] for s in PIPELINED_SHAPES]
+PANEL_IDS = [s[0] for s in MULTI_PANEL_SHAPES]
 
 
-@pytest.mark.parametrize("name,n,d,k", PIPELINED_SHAPES, ids=PIPE_IDS)
+@pytest.mark.parametrize("name,n,d,k", MULTI_PANEL_SHAPES, ids=PANEL_IDS)
 @pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16", "f16"])
-def test_fused_assign_reduce_pipelined_conforms(name, n, d, k, dtype):
-    """The explicit double-buffered DMA variant against the oracle: same
-    contract as fused_assign_reduce, input stream driven by manual
-    HBM->VMEM copies instead of BlockSpec streaming."""
-    from repro.kernels.fused_lloyd import fused_assign_reduce_pipelined_pallas
+def test_fused_assign_reduce_multi_panel_conforms(name, n, d, k, dtype):
+    """The resident-center Lloyd sweep against the oracle over a walk of
+    many 128-point panels (the accumulators persist across the grid)."""
+    from repro.kernels.fused_lloyd import fused_assign_reduce_pallas
     x, w, c, valid = _data(n, d, k, dtype, seed=8 * n + d + k)
     tol, tight = _tols(dtype)
     for cv in (None, valid):
         s_r, c_r, cost_r = ref.fused_assign_reduce_ref(x, w, c, cv)
-        s_o, c_o, cost_o = fused_assign_reduce_pipelined_pallas(
+        s_o, c_o, cost_o = fused_assign_reduce_pallas(
             x, w, c, cv, interpret=True, bn=128)
         np.testing.assert_allclose(s_o, s_r, rtol=tol, atol=tol)
         np.testing.assert_allclose(c_o, c_r, rtol=tight, atol=tight)
         np.testing.assert_allclose(cost_o, cost_r, rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("name,n,d,k", PIPELINED_SHAPES, ids=PIPE_IDS)
+@pytest.mark.parametrize("name,n,d,k", MULTI_PANEL_SHAPES, ids=PANEL_IDS)
 @pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16", "f16"])
-def test_update_min_dist_pipelined_conforms(name, n, d, k, dtype):
-    """The seeding variant double-buffers the OUTPUT stream too (per-panel
-    VMEM->HBM write-back DMA with slot-reuse drains) — the riskiest DMA
-    choreography in the kernel layer, so it gets its own grid."""
-    from repro.kernels.fused_lloyd import update_min_dist_pipelined_pallas
+def test_update_min_dist_multi_panel_conforms(name, n, d, k, dtype):
+    """The seeding sweep writes a (1, n) min-d2 row panel by panel; every
+    panel's slice of the output must line up with the oracle's."""
+    from repro.kernels.fused_lloyd import update_min_dist_pallas
     kc = min(k, 37)
     x, w, c, valid = _data(n, d, kc, dtype, seed=9 * n + d + k)
     rng = np.random.default_rng(n + d)
@@ -329,28 +328,26 @@ def test_update_min_dist_pipelined_conforms(name, n, d, k, dtype):
     tol, _ = _tols(dtype)
     for cv in (None, valid[:kc]):
         d2_r, m_r = ref.update_min_dist_ref(x, w, c, d2, cv)
-        d2_o, m_o = update_min_dist_pipelined_pallas(
+        d2_o, m_o = update_min_dist_pallas(
             x, w, c, d2, cv, interpret=True, bn=128)
         np.testing.assert_allclose(d2_o, d2_r, rtol=tol, atol=tol)
         np.testing.assert_allclose(m_o, m_r, rtol=tol)
 
 
-def test_pipelined_dispatch_matches_ref(monkeypatch):
-    """ops dispatches to the pipelined variants above _PIPELINE_MIN_N —
-    lower the threshold and check the public entry points still conform
-    (under REPRO_KERNEL_BACKEND=ref this exercises the oracle as usual)."""
-    monkeypatch.setattr(ops, "_PIPELINE_MIN_N", 256)
-    x, w, c, valid = _data(700, 8, 5, jnp.float32, seed=12)
-    s_r, c_r, cost_r = ref.fused_assign_reduce_ref(x, w, c, valid)
-    s_o, c_o, cost_o = ops.fused_assign_reduce(x, w, c, valid)
-    np.testing.assert_allclose(s_o, s_r, rtol=2e-3, atol=2e-3)
-    np.testing.assert_allclose(c_o, c_r, rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(cost_o, cost_r, rtol=2e-3)
-    d2 = jnp.asarray(np.random.default_rng(13).random(700), jnp.float32)
-    d2_r, m_r = ref.update_min_dist_ref(x, w, c, d2, valid)
-    d2_o, m_o = ops.update_min_dist(x, w, c, d2, valid)
-    np.testing.assert_allclose(d2_o, d2_r, rtol=2e-3, atol=2e-3)
-    np.testing.assert_allclose(m_o, m_r, rtol=2e-3)
+def test_multi_panel_removal_matches_ref():
+    """The removal sweep over several machines AND several panels each:
+    the (m, 1, p) mask rows and (m, 1, 1) live counts index by machine."""
+    from repro.kernels.fused_lloyd import remove_below_pallas
+    rng = np.random.default_rng(12)
+    x = jnp.asarray(rng.normal(size=(3, 700, 8)), jnp.float32)
+    c = jnp.asarray(rng.normal(size=(5, 8)), jnp.float32)
+    alive = jnp.asarray(rng.random((3, 700)) > 0.2)
+    d2, _ = ref.min_dist_ref(x.reshape(-1, 8), c)
+    v = jnp.median(d2) + 1e-4
+    a_r, l_r = ref.remove_below_ref(x, c, alive, v)
+    a_o, l_o = remove_below_pallas(x, c, alive, v, interpret=True, bn=128)
+    np.testing.assert_array_equal(np.asarray(a_o), np.asarray(a_r))
+    np.testing.assert_array_equal(np.asarray(l_o), np.asarray(l_r))
 
 
 @pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16", "f16"])

@@ -16,7 +16,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs.base import ArchConfig
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 from repro.models import moe
 from repro.sharding.activations import activation_mesh
 
@@ -30,7 +30,8 @@ for E, name in ((8, "ep"), (2, "local")):
     p = moe.init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 4, 32)) * 0.5
     out_d, aux_d = moe._moe_apply_dense(p, cfg, x, 8.0)
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with mesh, activation_mesh(mesh):
         out_s, aux_s = jax.jit(lambda p, x: moe.moe_apply(p, cfg, x))(p, x)
         g = jax.jit(jax.grad(

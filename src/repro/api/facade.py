@@ -18,6 +18,7 @@ straggler deadlines through the host loop's ``on_round`` hook.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
@@ -27,6 +28,12 @@ from repro.api.backends import resolve_backend
 from repro.api.registry import get_algorithm
 from repro.api.result import ClusterResult
 from repro.obs import trace as obs_trace
+from repro.obs.metrics import REGISTRY
+
+# One line per traced fit, newest last: its wall, its counters and the
+# seconds of each span name, readable by whoever holds the process (the
+# chip benchmark's per-layer metrics read the window's fits from here).
+TRACED_FITS = REGISTRY.event_log("api.fit.traces")
 
 
 def _as_parts(x: np.ndarray, w, m: int, seed: int, policy):
@@ -111,9 +118,16 @@ def fit(x, k: int, algo: str = "soccer", backend="auto", *,
         per-round structured records (live count, realized alpha,
         removal threshold, stopping-rule margin, uplink rows, achieved
         wire bytes, wall/compile split) land in
-        ``result.extra["trace"]``; "full" — additionally records
-        span/event timelines for the Chrome-trace/Perfetto export
-        (``repro.obs.export``, ``python -m repro.obs.report``).
+        ``result.extra["trace"]``, with per-fit counters (host<->device
+        bytes, programs traced); "full" — additionally records the
+        host phases as spans (input placement, program builds, each
+        round, finalize, readback), mirrored into
+        ``jax.profiler.TraceAnnotation`` so a captured profile shows
+        them on its host plane, plus events, for the
+        Chrome-trace/Perfetto export (``repro.obs.export``,
+        ``python -m repro.obs.report``). Each traced fit also appends
+        its totals to the ``api.fit.traces`` event log of
+        ``repro.obs.REGISTRY``.
       **algo_params: algorithm-specific knobs (e.g. ``epsilon`` for
         soccer, ``rounds`` for kmeans_parallel); unknown names raise.
 
@@ -121,24 +135,23 @@ def fit(x, k: int, algo: str = "soccer", backend="auto", *,
       A ``ClusterResult`` with a uniform telemetry shape for every
       algorithm x backend combination.
     """
-    x = np.asarray(x)
-    if x.ndim not in (2, 3):
-        raise ValueError(f"x must be (n, d) or (m, p, d), got {x.shape}")
-    if x.ndim == 3:
-        if m is not None and m != x.shape[0]:
+    shape = np.shape(x)
+    if len(shape) not in (2, 3):
+        raise ValueError(f"x must be (n, d) or (m, p, d), got {shape}")
+    if len(shape) == 3:
+        if m is not None and m != shape[0]:
             raise ValueError(
-                f"m={m} conflicts with pre-sharded x of {x.shape[0]} "
+                f"m={m} conflicts with pre-sharded x of {shape[0]} "
                 f"machines")
         if shard_policy is not None:
             raise ValueError(
                 "shard_policy only applies to flat (n, d) input; "
                 "pre-sharded (m, p, d) data is passed through untouched")
-        m = x.shape[0]
+        m = shape[0]
     else:
         m = 8 if m is None else m
     policy = shard_policy if shard_policy is not None else (
         "shuffle" if shuffle else "contiguous")
-    parts, w_parts, alive_parts = _as_parts(x, w, m, seed, policy)
 
     bk = resolve_backend(backend, m, uplink_dtype=uplink_dtype,
                          uplink_wire=uplink_wire)
@@ -156,6 +169,7 @@ def fit(x, k: int, algo: str = "soccer", backend="auto", *,
                 f"algorithms registered with supports_uplink_mode")
         algo_params["uplink_mode"] = uplink_mode
 
+    init_dead = ()
     if failure_plan is not None:
         if not getattr(driver, "supports_failure_plan", False):
             raise TypeError(
@@ -164,9 +178,6 @@ def fit(x, k: int, algo: str = "soccer", backend="auto", *,
                 f"algorithms registered with supports_failure_plan")
         _check_plan_machines(failure_plan, m)
         init_dead = failure_plan.initial_failures()
-        if init_dead:
-            w_parts, alive_parts = _mask_failed_machines(
-                parts, w_parts, alive_parts, init_dead)
         algo_params["on_round"] = failure_plan.chain(
             algo_params.get("on_round"))
         if failure_plan.straggler_rate:
@@ -179,19 +190,26 @@ def fit(x, k: int, algo: str = "soccer", backend="auto", *,
             algo=algo, backend=type(bk).__name__, k=k, m=m, seed=seed))
 
     # every fit is timed by the one obs clock (repro.obs.trace.clock) so
-    # bench walls and trace walls can never come from different timers
+    # bench walls and trace walls can never come from different timers;
+    # the wall covers the input's placement too
     t0 = obs_trace.clock()
-    if rt is None:
+    with (obs_trace.run_trace(rt) if rt is not None
+          else contextlib.nullcontext()):
+        with obs_trace.span("fit.place"):
+            x = obs_trace.to_host(x)
+            parts, w_parts, alive_parts = _as_parts(x, w, m, seed, policy)
+            if init_dead:
+                w_parts, alive_parts = _mask_failed_machines(
+                    parts, w_parts, alive_parts, init_dead)
         res = driver(parts, k, backend=bk, key=key, w=w_parts,
                      alive=alive_parts, seed=seed, **algo_params)
-    else:
-        with obs_trace.run_trace(rt):
-            res = driver(parts, k, backend=bk, key=key, w=w_parts,
-                         alive=alive_parts, seed=seed, **algo_params)
     res.wall_time_s = obs_trace.clock() - t0
     if rt is not None:
         rt.wall_s = res.wall_time_s
         res.extra["trace"] = rt.summary()
+        TRACED_FITS.append(algo=algo, mode=rt.mode, wall_s=rt.wall_s,
+                           counters=dict(rt.counters),
+                           span_s=rt.span_seconds())
     res.params = dict(k=k, m=m, seed=seed, **algo_params)
     if shard_policy is not None:
         res.params["shard_policy"] = getattr(policy, "__name__", policy)

@@ -28,7 +28,8 @@ from jax import lax
 
 from repro.configs.soccer_paper import SoccerParams
 from repro.core.comm import WireTally, wire_tally
-from repro.obs.trace import clock, current_trace, timed_compile
+from repro.obs import trace as obs_trace
+from repro.obs.trace import current_trace, span, timed_compile
 from repro.core.kmeans import kmeans
 from repro.core.minibatch import minibatch_kmeans
 from repro.core.sampling import draw_global_sample
@@ -193,6 +194,7 @@ def _draw_sample(comm, const: SoccerConstants, key: jax.Array,
 
 def soccer_round(state: SoccerState, comm, const: SoccerConstants
                  ) -> SoccerState:
+    obs_trace.count("programs_traced")      # runs once per trace, not per call
     key, k_s1, k_s2, k_bb, k_strag1, k_strag2 = jax.random.split(
         state.key, 6)
     alive_eff = state.alive & state.machine_ok[:, None]
@@ -279,6 +281,7 @@ def soccer_finalize(state: SoccerState, comm, const: SoccerConstants
     the HT weights before the final fit — the blackbox never spends
     centers chasing the z farthest cost units.
     """
+    obs_trace.count("programs_traced")      # runs once per trace, not per call
     key, k_bb = jax.random.split(state.key)
     alive_eff = state.alive & state.machine_ok[:, None]
     n_local = jnp.sum(alive_eff, axis=1).astype(jnp.int32)
@@ -328,8 +331,8 @@ class SoccerResult:
 
 
 def flatten_centers(state: SoccerState) -> np.ndarray:
-    c = np.asarray(state.centers)
-    valid = np.asarray(state.centers_valid)
+    c = obs_trace.to_host(state.centers)
+    valid = obs_trace.to_host(state.centers_valid)
     return c[valid]
 
 
@@ -400,8 +403,14 @@ def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
         uplink_wire=check_uplink_wire(
             getattr(backend, "uplink_wire", "auto"), ud))
     key = jax.random.PRNGKey(params.seed) if key is None else key
-    state = init_state(jnp.asarray(x_parts), const, key, w=w, alive=alive)
-    state = backend.put(state, STATE_MARKS)
+    trace = current_trace()
+    with span("soccer.place"):
+        obs_trace.count_put(x_parts, w, alive)
+        state = init_state(jnp.asarray(x_parts), const, key, w=w,
+                           alive=alive)
+        state = backend.put(state, STATE_MARKS)
+        if trace is not None:   # the copy lands here, not in round 1
+            jax.block_until_ready(state)
 
     step = backend.compile(
         functools.partial(soccer_round, comm=comm, const=const),
@@ -418,7 +427,6 @@ def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
     rounds = 0
     prev_n = math.inf
     t_round, t_fin = WireTally(), WireTally()
-    trace = current_trace()
     round_walls = []
     compile_round = compile_fin = fin_wall = None
     if trace is not None:
@@ -430,23 +438,30 @@ def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
         # the collectives, so it MUST run inside the same wire tally the
         # first inline call would have recorded into; on backends without
         # a working .lower the fallback leaves compile inline (absorbed
-        # into round 1's wall, exactly the untraced behavior).
-        with wire_tally(t_round):
-            step, compile_round = timed_compile(step, state)
-        with wire_tally(t_fin):
-            fin, compile_fin = timed_compile(fin, state)
-    while rounds < const.max_rounds and stopping_rule(
-            int(state.n_remaining), const.eta, prev_n):
-        prev_n = int(state.n_remaining)
+        # into round 1's wall, exactly the untraced behavior). Each
+        # record's wall and compile time is its span's own interval.
+        with wire_tally(t_round), span("soccer.compile",
+                                       program="round") as sp:
+            step, compile_s = timed_compile(step, state)
+        compile_round = None if compile_s is None else sp.t1 - sp.t0
+        with wire_tally(t_fin), span("soccer.compile",
+                                     program="finalize") as sp:
+            fin, compile_s = timed_compile(fin, state)
+        compile_fin = None if compile_s is None else sp.t1 - sp.t0
+    while rounds < const.max_rounds:
+        n_live = int(obs_trace.to_host(state.n_remaining))
+        if not stopping_rule(n_live, const.eta, prev_n):
+            break
+        prev_n = n_live
         if trace is None:
             with wire_tally(t_round):   # records once, at the round's trace
                 state = step(state)
         else:
-            t0 = clock()
-            with wire_tally(t_round):
-                state = step(state)
-            jax.block_until_ready(state.n_remaining)
-            round_walls.append(clock() - t0)
+            with span("soccer.round", round=rounds + 1) as sp:
+                with wire_tally(t_round):
+                    state = step(state)
+                jax.block_until_ready(state.n_remaining)
+            round_walls.append(sp.t1 - sp.t0)
         rounds += 1
         if on_round is not None:
             state = on_round(rounds, state) or state
@@ -454,15 +469,22 @@ def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
         with wire_tally(t_fin):
             state = fin(state)
     else:
-        t0 = clock()
-        with wire_tally(t_fin):
-            state = fin(state)
-        jax.block_until_ready(state.centers)
-        fin_wall = clock() - t0
+        with span("soccer.finalize") as sp:
+            with wire_tally(t_fin):
+                state = fin(state)
+            jax.block_until_ready(state.centers)
+        fin_wall = sp.t1 - sp.t0
 
+    with span("soccer.readback"):
+        up = obs_trace.to_host(state.uplink)
+        n_hist = obs_trace.to_host(state.n_hist)
+        v_hist = obs_trace.to_host(state.v_hist)
+        centers = flatten_centers(state)
+        if trace is not None:
+            a_hist = obs_trace.to_host(state.alpha_hist)
+            n_rem = int(obs_trace.to_host(state.n_remaining))
     # achieved wire bytes: static per-trace payload + per-row widths of
     # the ragged channels x the realized row counts the state tracked
-    up = np.asarray(state.uplink)
     wire_payload = np.concatenate(
         [t_round.bytes_at(up[:rounds]),
          t_fin.bytes_at(up[rounds:rounds + 1])])
@@ -470,21 +492,22 @@ def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
         [t_round.meta_bytes_at(up[:rounds]),
          t_fin.meta_bytes_at(up[rounds:rounds + 1])])
     if trace is not None:
-        _emit_soccer_records(trace, state, const, rounds, prev_n, up,
-                             wire_payload, wire_meta, round_walls,
-                             fin_wall, compile_round, compile_fin)
+        _emit_soccer_records(trace, const, rounds, prev_n, n_rem, up,
+                             n_hist, v_hist, a_hist, wire_payload,
+                             wire_meta, round_walls, fin_wall,
+                             compile_round, compile_fin)
     return SoccerResult(
-        centers=flatten_centers(state), rounds=rounds, const=const,
-        n_hist=np.asarray(state.n_hist), v_hist=np.asarray(state.v_hist),
-        uplink=up, state=state,
+        centers=centers, rounds=rounds, const=const,
+        n_hist=n_hist, v_hist=v_hist, uplink=up, state=state,
         wire_payload=wire_payload, wire_meta=wire_meta)
 
 
-def _emit_soccer_records(trace, state: SoccerState, const: SoccerConstants,
-                         rounds: int, prev_n: float, up: np.ndarray,
-                         wire_payload: np.ndarray, wire_meta: np.ndarray,
-                         round_walls, fin_wall, compile_round,
-                         compile_fin) -> None:
+def _emit_soccer_records(trace, const: SoccerConstants, rounds: int,
+                         prev_n: float, n_rem: int, up: np.ndarray,
+                         n_hist: np.ndarray, v_hist: np.ndarray,
+                         a_hist: np.ndarray, wire_payload: np.ndarray,
+                         wire_meta: np.ndarray, round_walls, fin_wall,
+                         compile_round, compile_fin) -> None:
     """Turn the state histories into the pinned per-round records.
 
     ``n_hist[i]`` is N at the *start* of (0-indexed) round ``i``;
@@ -492,9 +515,6 @@ def _emit_soccer_records(trace, state: SoccerState, const: SoccerConstants,
     round ``r`` (1-based) is ``n_hist[r]`` for every r — that is the
     number the stopping rule compared against eta.
     """
-    n_hist = np.asarray(state.n_hist)
-    v_hist = np.asarray(state.v_hist)
-    a_hist = np.asarray(state.alpha_hist)
     for r in range(1, rounds + 1):
         n_after = int(n_hist[r])
         trace.emit_round(
@@ -516,7 +536,6 @@ def _emit_soccer_records(trace, state: SoccerState, const: SoccerConstants,
         wire_payload_bytes=wire_payload[rounds],
         wire_meta_bytes=wire_meta[rounds],
         wall_s=fin_wall, compile_s=compile_fin)
-    n_rem = int(state.n_remaining)
     if n_rem <= const.eta:
         trace.stop_reason = "capacity"
     elif prev_n != math.inf and n_rem >= prev_n:

@@ -5,8 +5,10 @@ Three pieces, all off by default and near-zero-cost when off:
 * ``repro.obs.trace`` — per-run structured tracing: ``RunTrace`` holds
   the per-round records every driver emits (round index, live count,
   realized alpha, removal threshold, stopping-rule margin, uplink rows,
-  achieved wire bytes, wall/compile split), plus ``Span``/``event``
-  timelines in ``trace="full"`` mode. Activated by the ``fit(trace=...)``
+  achieved wire bytes, wall/compile split) and per-run counters
+  (host<->device bytes, programs traced), plus ``Span``/``event``
+  timelines in ``trace="full"`` mode, the spans mirrored into
+  ``jax.profiler``. Activated by the ``fit(trace=...)``
   knob; drivers publish through the ambient ``current_trace()`` so no
   driver signature changes when tracing is off.
 * ``repro.obs.metrics`` — one registry over the repo's scattered

@@ -14,7 +14,8 @@ writes the standard ``{"traceEvents": [...]}`` JSON that chrome://tracing
 and Perfetto's UI open directly: spans become ``"ph": "X"`` duration
 events, per-round records become synthetic duration events on a
 ``rounds`` track (built from ``wall_s`` even in mode="rounds", which has
-no spans), carrying the full record in ``args`` for inspection.
+no spans), carrying the full record in ``args`` for inspection; the
+run's counters become one ``"ph": "C"`` counter event.
 """
 from __future__ import annotations
 
@@ -27,7 +28,8 @@ from repro.obs.trace import ROUND_FIELDS
 PathLike = Union[str, pathlib.Path]
 
 _RUN_KEYS = ("mode", "meta", "stop_reason", "rounds_to_margin", "wall_s",
-             "compile_s", "wire_payload_bytes", "wire_meta_bytes")
+             "compile_s", "wire_payload_bytes", "wire_meta_bytes",
+             "counters")
 
 
 def _jsonable(obj):
@@ -140,6 +142,9 @@ def chrome_trace_events(summary: Dict[str, Any],
             "name": ev["name"], "ph": "i", "pid": pid, "tid": 2,
             "ts": _us(ev["t"] - base), "s": "t",
             "args": dict(ev.get("attrs") or {})})
+    if summary.get("counters"):
+        events.append({"name": "counters", "ph": "C", "pid": pid,
+                       "ts": 0.0, "args": dict(summary["counters"])})
     return events
 
 

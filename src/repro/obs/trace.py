@@ -3,21 +3,26 @@
 Design constraints (the tentpole contract):
 
 * **Off by default, provably near-zero-cost when off.** Every public
-  hook (``span``/``event``/``emit_round``/``current_trace``) is one
-  list-truthiness check when no trace is active — no allocation, no
-  clock read, no string formatting. The module stat counter ``_STATS``
-  lets tests assert that an untraced ``fit()`` allocated zero spans and
-  zero traces.
+  hook (``span``/``event``/``count``/``emit_round``/``current_trace``)
+  is one list-truthiness check when no trace is active — no allocation,
+  no clock read, no string formatting. The module stat counter
+  ``_STATS`` lets tests assert that an untraced ``fit()`` allocated zero
+  spans, counts and traces.
 * **Injectable clock.** All timestamps come from the module clock
   (``clock()``, default ``time.perf_counter``); ``set_clock`` swaps it
   for a fake in tests so span durations and round walls are
   deterministic. Everything in the repo that times a fit (the facade,
   the scenario sweep, the overhead gate) reads THIS clock, so bench
   numbers and trace numbers can never come from two different timers.
-* **Optional ``jax.profiler.TraceAnnotation`` passthrough.** In
-  ``mode="full"`` with ``annotate=True``, each span also opens a
-  profiler annotation so the repo's spans line up with XLA's own
-  timeline in a captured profile. jax is imported lazily, only then.
+* **Spans on the profiler's clock.** In ``mode="full"`` each span also
+  opens a ``jax.profiler.TraceAnnotation`` of the same name, so the
+  repo's spans land on the host plane of a captured profile, on the
+  clock of the device trace. jax is imported lazily, only then.
+* **Per-run counters.** ``count(name, n)`` adds to the active trace's
+  ``counters`` in either mode: the SOCCER path counts the bytes it moves
+  between host and device (``h2d_bytes``/``d2h_bytes``, through
+  ``to_host``/``count_put``) and the programs it traces
+  (``programs_traced``).
 
 The per-round record schema is pinned (``ROUND_SCHEMA``): field names
 and value types are part of the exported JSONL contract and covered by a
@@ -30,6 +35,8 @@ import collections
 import contextlib
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+import numpy as np
 
 # ------------------------------------------------------------------ clock
 
@@ -79,7 +86,8 @@ PHASES = ("round", "finalize", "upload")
 TRACE_MODES = ("rounds", "full")
 
 # Allocation stats for the zero-overhead-when-off test: traces created,
-# spans entered, records emitted. Incremented only on the active paths.
+# spans entered, counts added, records emitted. Incremented only on the
+# active paths.
 _STATS = collections.Counter()
 
 
@@ -109,11 +117,13 @@ def round_record(**fields) -> Dict[str, Any]:
 
 
 class Span:
-    """One named, timed interval inside a ``mode="full"`` trace.
+    """One named, timed interval of an active trace.
 
-    Records ``(name, t0, t1, attrs)`` on exit; optionally mirrors itself
-    into ``jax.profiler.TraceAnnotation`` so repo spans land in captured
-    device profiles.
+    Times ``(t0, t1)`` in either mode, so the SOCCER loop reads its
+    round and compile walls from it. Only ``mode="full"`` records it in
+    the trace's ``spans`` and mirrors it into
+    ``jax.profiler.TraceAnnotation``, which puts it on the host plane of
+    a captured profile.
     """
 
     __slots__ = ("name", "attrs", "t0", "t1", "_trace", "_annotation")
@@ -127,7 +137,7 @@ class Span:
 
     def __enter__(self) -> "Span":
         _STATS["spans"] += 1
-        if self._trace.annotate:
+        if self._trace.mode == "full":
             from jax.profiler import TraceAnnotation
             self._annotation = TraceAnnotation(self.name)
             self._annotation.__enter__()
@@ -138,13 +148,9 @@ class Span:
         self.t1 = clock()
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
-        self._trace.spans.append(
-            {"name": self.name, "t0": self.t0, "t1": self.t1,
-             "attrs": self.attrs})
-
-    @property
-    def duration_s(self) -> float:
-        return self.t1 - self.t0
+            self._trace.spans.append(
+                {"name": self.name, "t0": self.t0, "t1": self.t1,
+                 "attrs": self.attrs})
 
 
 class _NullSpan:
@@ -168,28 +174,27 @@ _NULL_SPAN = _NullSpan()
 class RunTrace:
     """The per-run trace container behind ``fit(trace=...)``.
 
-    ``mode="rounds"`` collects only per-round records plus the run-level
-    wall/compile split; ``mode="full"`` additionally records spans and
-    events (and, with ``annotate=True``, mirrors spans into
-    ``jax.profiler``). ``summary()`` is the exported, JSON-clean shape
-    that lands in ``ClusterResult.extra["trace"]`` and the JSONL/Chrome
-    exporters consume.
+    ``mode="rounds"`` collects per-round records, the run-level
+    wall/compile split and the counters; ``mode="full"`` additionally
+    records spans (mirrored into ``jax.profiler``) and events.
+    ``summary()`` is the exported, JSON-clean shape that lands in
+    ``ClusterResult.extra["trace"]`` and the JSONL/Chrome exporters
+    consume.
     """
 
     def __init__(self, mode: str = "rounds", *,
-                 meta: Optional[Dict[str, Any]] = None,
-                 annotate: bool = False):
+                 meta: Optional[Dict[str, Any]] = None):
         if mode not in TRACE_MODES:
             raise ValueError(
                 f"unknown trace mode {mode!r}: expected one of "
                 f"{TRACE_MODES} (or trace off)")
         _STATS["traces"] += 1
         self.mode = mode
-        self.annotate = annotate and mode == "full"
         self.meta: Dict[str, Any] = dict(meta or {})
         self.records: List[Dict[str, Any]] = []
         self.spans: List[Dict[str, Any]] = []
         self.events: List[Dict[str, Any]] = []
+        self.counters: Dict[str, int] = {}
         self.stop_reason: Optional[str] = None
         self.wall_s: Optional[float] = None
         self.t_start = clock()
@@ -201,10 +206,12 @@ class RunTrace:
         self.records.append(rec)
         return rec
 
-    def span(self, name: str, **attrs):
-        if self.mode != "full":
-            return _NULL_SPAN
+    def span(self, name: str, **attrs) -> Span:
         return Span(name, self, attrs)
+
+    def count(self, name: str, n: int = 1) -> None:
+        _STATS["counts"] += 1
+        self.counters[name] = self.counters.get(name, 0) + int(n)
 
     def event(self, name: str, **attrs) -> None:
         if self.mode != "full":
@@ -235,6 +242,13 @@ class RunTrace:
                 return rec["round"]
         return None
 
+    def span_seconds(self) -> Dict[str, float]:
+        """Seconds of the recorded spans, summed per span name."""
+        out: Dict[str, float] = {}
+        for sp in self.spans:
+            out[sp["name"]] = out.get(sp["name"], 0.0) + sp["t1"] - sp["t0"]
+        return out
+
     def summary(self) -> Dict[str, Any]:
         return {
             "mode": self.mode,
@@ -245,6 +259,7 @@ class RunTrace:
             "compile_s": self.compile_s,
             "wire_payload_bytes": self.wire_payload_total,
             "wire_meta_bytes": self.wire_meta_total,
+            "counters": dict(self.counters),
             "records": [dict(r) for r in self.records],
             "spans": [dict(s) for s in self.spans],
             "events": [dict(e) for e in self.events],
@@ -272,11 +287,41 @@ def run_trace(trace: RunTrace):
 
 
 def span(name: str, **attrs):
-    """Ambient span: a real Span inside an active full trace, else a
-    shared no-op (no allocation when tracing is off)."""
+    """Ambient span: a real Span inside an active trace (recorded and
+    mirrored into the profiler in mode "full"), else a shared no-op (no
+    allocation when tracing is off)."""
     if not _STACK:
         return _NULL_SPAN
     return _STACK[-1].span(name, **attrs)
+
+
+def count(name: str, n: int = 1) -> None:
+    """Ambient counter: adds ``n`` to the active trace's ``counters``
+    (no-op unless a trace is active)."""
+    if not _STACK:
+        return
+    _STACK[-1].count(name, n)
+
+
+def to_host(a) -> np.ndarray:
+    """``np.asarray(a)``; a device array's ``nbytes`` count as
+    ``d2h_bytes`` of the active trace."""
+    if _STACK:
+        from jax import Array
+        if isinstance(a, Array):
+            _STACK[-1].count("d2h_bytes", a.nbytes)
+    return np.asarray(a)
+
+
+def count_put(*arrays) -> None:
+    """Count the host (NumPy) arrays among ``arrays`` as ``h2d_bytes`` of
+    the active trace. Call it where they are put on a device; arrays
+    already on a device move device to device and are not counted."""
+    if not _STACK:
+        return
+    for a in arrays:
+        if isinstance(a, np.ndarray):
+            _STACK[-1].count("h2d_bytes", a.nbytes)
 
 
 def event(name: str, **attrs) -> None:

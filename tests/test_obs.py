@@ -16,6 +16,7 @@ from repro.api import fit
 from repro.obs import REGISTRY, MetricsRegistry
 from repro.obs.export import (chrome_trace_events, load_jsonl, write_jsonl)
 from repro.obs.report import format_diff, format_summary, main as report_main
+from repro.obs import trace as obs_trace
 from repro.obs.trace import (ROUND_FIELDS, ROUND_SCHEMA, RunTrace, _STATS,
                              round_record, run_trace)
 
@@ -67,11 +68,17 @@ def test_round_record_coerces_and_rejects():
 
 def test_trace_off_allocates_nothing():
     """The zero-cost contract: an untraced fit touches none of the trace
-    machinery — no RunTrace, no spans, no records, no 'trace' key."""
+    machinery — no RunTrace, no spans, no counts, no records, no 'trace'
+    key — and the ambient hooks are no-ops with no trace active."""
     x = _data()
     _soccer(x)                                # warm (compile may span)
     before = dict(_STATS)
     res = _soccer(x)
+    obs_trace.count("programs_traced")
+    obs_trace.count_put(x)
+    obs_trace.to_host(jax.numpy.zeros(3))
+    with obs_trace.span("off"):
+        pass
     assert dict(_STATS) == before
     assert "trace" not in res.extra
 
@@ -135,6 +142,99 @@ def test_full_mode_records_spans_and_events():
     assert len(summary["spans"]) == 1 and len(summary["events"]) == 1
 
 
+# ------------------------------------------------------------ fit spans
+
+
+def _full_soccer(x):
+    res = _soccer(x, trace="full")
+    assert res.rounds >= 1                    # the loop ran
+    return res, res.extra["trace"]
+
+
+def test_traced_fit_spans_every_host_phase_in_order():
+    res, t = _full_soccer(_data(p=2048))
+    assert [s["name"] for s in t["spans"]] == (
+        ["fit.place", "soccer.place", "soccer.compile", "soccer.compile"]
+        + ["soccer.round"] * res.rounds
+        + ["soccer.finalize", "soccer.readback"])
+    assert [s["attrs"] for s in t["spans"] if s["name"] == "soccer.compile"
+            ] == [{"program": "round"}, {"program": "finalize"}]
+    assert [s["attrs"]["round"] for s in t["spans"]
+            if s["name"] == "soccer.round"] == list(range(1, res.rounds + 1))
+    # inside the fit's wall, and not overlapping each other
+    for a, b in zip(t["spans"], t["spans"][1:]):
+        assert a["t0"] <= a["t1"] <= b["t0"] <= b["t1"]
+    covered = sum(s["t1"] - s["t0"] for s in t["spans"])
+    assert 0 < covered <= t["wall_s"]
+
+
+def test_span_durations_are_the_records_walls():
+    """One measurement, not two: each round, finalize and compile span's
+    interval is its record's wall_s / compile_s."""
+    res, t = _full_soccer(_data(p=2048))
+    dur = {}
+    for s in t["spans"]:
+        dur.setdefault(s["name"], []).append(s["t1"] - s["t0"])
+    recs = t["records"]
+    assert [r["wall_s"] for r in recs[:-1]] == dur["soccer.round"]
+    assert [recs[-1]["wall_s"]] == dur["soccer.finalize"]
+    assert [recs[0]["compile_s"], recs[-1]["compile_s"]] == dur[
+        "soccer.compile"]
+
+
+@pytest.mark.parametrize("on_device", [False, True])
+def test_fit_counts_programs_and_host_device_bytes(on_device):
+    x = _data(p=2048)
+    xin = jax.numpy.asarray(x) if on_device else x
+    res = fit(xin, K, algo="soccer", backend="virtual", epsilon=0.2,
+              seed=0, trace="rounds")
+    t = res.extra["trace"]
+    state = res.extra["state"]
+    # the stopping rule read the live count before every round and once
+    # more unless max_rounds ended the loop; the readback reads it again
+    checks = res.rounds + (t["stop_reason"] != "max_rounds")
+    readback = sum(a.nbytes for a in (
+        state.uplink, state.n_hist, state.v_hist, state.alpha_hist,
+        state.centers, state.centers_valid, state.n_remaining))
+    assert t["counters"] == {
+        "programs_traced": 2,
+        "h2d_bytes": x.nbytes,
+        "d2h_bytes": (x.nbytes if on_device else 0) + readback
+                     + checks * state.n_remaining.nbytes}
+    line = REGISTRY.read("api.fit.traces")["api.fit.traces"]["events"][-1]
+    assert line["counters"] == t["counters"]
+    assert line["wall_s"] == t["wall_s"] and line["span_s"] == {}
+
+
+def test_spans_land_on_the_profiler_host_plane(tmp_path):
+    """Full mode mirrors every span into jax.profiler: a captured profile
+    holds each on the host plane under its own name, as long as the
+    RunTrace measured it (within 1 ms)."""
+    x = _data(p=2048)
+    _soccer(x, trace="full")                  # compile outside the capture
+    with jax.profiler.trace(str(tmp_path)):
+        res = _soccer(x, trace="full")
+    spans = res.extra["trace"]["spans"]
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    prof = jax.profiler.ProfileData.from_file(str(path))
+    host = [p for p in prof.planes if p.name == "/host:CPU"]
+    assert len(host) == 1
+    names = {s["name"] for s in spans}
+    seen = {}
+    for line in host[0].lines:
+        for ev in line.events:
+            if ev.name in names:
+                seen.setdefault(ev.name, []).append(
+                    (ev.start_ns, ev.duration_ns * 1e-9))
+    assert set(seen) == names
+    for name in names:
+        ours = [s["t1"] - s["t0"] for s in spans if s["name"] == name]
+        theirs = [d for _, d in sorted(seen[name])]
+        assert len(theirs) == len(ours), name
+        for a, b in zip(ours, theirs):
+            assert abs(a - b) < 1e-3, (name, a, b)
+
+
 # ------------------------------------------------------------ export
 
 
@@ -148,6 +248,7 @@ def test_jsonl_round_trip(tmp_path):
     assert runs[0]["records"] == a["records"]
     assert runs[1]["stop_reason"] == b["stop_reason"]
     assert runs[0]["wire_payload_bytes"] == a["wire_payload_bytes"]
+    assert runs[0]["counters"] == a["counters"] and a["counters"]
 
 
 def test_jsonl_orphan_line_raises(tmp_path):
@@ -169,6 +270,7 @@ def test_chrome_trace_export(tmp_path):
                     if e["tid"] == 1)
     for (ts0, d0), (ts1, _) in zip(rounds, rounds[1:]):
         assert abs((ts0 + d0) - ts1) < 1.0    # contiguous (us resolution)
+    assert [e["args"] for e in events if e["ph"] == "C"] == [t["counters"]]
 
 
 # ------------------------------------------------------------ report CLI

@@ -15,6 +15,11 @@ Drivers describe each compiled function's arguments/results with a
 sharded on a mesh) or ``REPLICATED`` (identical on every machine). The
 backend translates marks into PartitionSpecs (mesh) or ignores them
 (virtual) — the same driver loop then runs unchanged in both modes.
+
+The backends are frozen dataclasses, so they hash by value: SOCCER's
+driver keys its cache of compiled programs on the backend and its comm,
+and a backend or comm that cannot be hashed gets programs built anew
+on every fit.
 """
 from __future__ import annotations
 

@@ -16,10 +16,11 @@ real deployment pays. All shapes are static; removed points are masked.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
-from typing import NamedTuple, Optional
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +36,7 @@ from repro.core.minibatch import minibatch_kmeans
 from repro.core.sampling import draw_global_sample
 from repro.core.truncated_cost import removal_threshold, trim_top_mass
 from repro.kernels import ops
+from repro.kernels.tuning import autotune_mode
 
 
 @dataclasses.dataclass(frozen=True)
@@ -376,6 +378,95 @@ def stopping_rule(remaining: float, capacity: float, prev: float) -> bool:
     return remaining > capacity and remaining < prev
 
 
+# ------------------------------------------------------ the program cache
+
+# SOCCER's round and finalize programs, built once per process and key
+# (``_program_key``): a fit whose key is here traces nothing. LRU, so a
+# sweep over many shapes holds at most this many pairs.
+_PROGRAM_CACHE_SIZE = 8
+_PROGRAMS: "collections.OrderedDict[tuple, Tuple[_Program, _Program]]" = (
+    collections.OrderedDict())
+
+
+@dataclasses.dataclass
+class _Program:
+    """One compiled SOCCER program and what its last trace recorded."""
+    fn: Any                              # backend.compile(...)
+    tally: Optional[WireTally] = None    # wire bytes; None until traced
+    aot: Any = None                      # timed_compile's executable,
+                                         # made by the first traced fit
+
+
+def clear_program_cache() -> None:
+    """Forget every cached program: the next fit builds both anew."""
+    _PROGRAMS.clear()
+
+
+def _program_key(backend, comm, const: SoccerConstants,
+                 state: SoccerState) -> tuple:
+    """Everything that decides what tracing the two programs produces:
+    the placement, the constants, every state leaf's aval and sharding,
+    and the trace-time kernel settings."""
+    leaves = tuple((jnp.shape(a), jnp.result_type(a),
+                    getattr(a, "weak_type", False),
+                    getattr(a, "sharding", None))
+                   for a in jax.tree.leaves(state))
+    return (backend, comm, const, leaves, ops._backend(None),
+            autotune_mode())
+
+
+def _programs(backend, comm, const: SoccerConstants, state: SoccerState
+              ) -> Tuple[_Program, _Program]:
+    """The (round, finalize) programs for this fit: those an earlier fit
+    with the same key built, else new ones, cached unless part of the key
+    cannot be hashed (a caller-supplied comm, say)."""
+    key = _program_key(backend, comm, const, state)
+    try:
+        progs = _PROGRAMS.get(key)
+    except TypeError:
+        key = progs = None
+    if progs is not None:
+        _PROGRAMS.move_to_end(key)
+        return progs
+    progs = tuple(_Program(backend.compile(
+        functools.partial(body, comm=comm, const=const),
+        (STATE_MARKS,), STATE_MARKS))
+        for body in (soccer_round, soccer_finalize))
+    if key is not None:
+        _PROGRAMS[key] = progs
+        if len(_PROGRAMS) > _PROGRAM_CACHE_SIZE:
+            _PROGRAMS.popitem(last=False)
+    return progs
+
+
+def _traced(prog: _Program, fn, *args):
+    """``fn(*args)`` under a fresh wire tally. If it traced ``prog`` (the
+    tally recorded something, or ``prog`` was never traced), the tally
+    replaces the stored one: a retrace never adds to it."""
+    fresh = WireTally()
+    with wire_tally(fresh):
+        out = fn(*args)
+    if prog.tally is None or fresh != WireTally():
+        prog.tally = fresh
+    return out
+
+
+def _compile_ahead(prog: _Program, state: SoccerState,
+                   name: str) -> Optional[float]:
+    """A traced fit's AOT executable for ``prog``: compiled in a
+    ``soccer.compile`` span whose interval is returned, or 0.0 when an
+    earlier traced fit compiled it. None where the backend cannot lower
+    ahead of time (the first call then compiles inline)."""
+    if prog.aot is not None:
+        return 0.0
+    with span("soccer.compile", program=name) as sp:
+        aot, compile_s = _traced(prog, timed_compile, prog.fn, state)
+    if compile_s is None:
+        return None
+    prog.aot = aot
+    return sp.t1 - sp.t0
+
+
 def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
                backend=None,
                key: Optional[jax.Array] = None,
@@ -404,6 +495,7 @@ def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
             getattr(backend, "uplink_wire", "auto"), ud))
     key = jax.random.PRNGKey(params.seed) if key is None else key
     trace = current_trace()
+    obs_trace.count("programs_traced", 0)   # reported on a cache hit too
     with span("soccer.place"):
         obs_trace.count_put(x_parts, w, alive)
         state = init_state(jnp.asarray(x_parts), const, key, w=w,
@@ -411,13 +503,9 @@ def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
         state = backend.put(state, STATE_MARKS)
         if trace is not None:   # the copy lands here, not in round 1
             jax.block_until_ready(state)
-
-    step = backend.compile(
-        functools.partial(soccer_round, comm=comm, const=const),
-        (STATE_MARKS,), STATE_MARKS)
-    fin = backend.compile(
-        functools.partial(soccer_finalize, comm=comm, const=const),
-        (STATE_MARKS,), STATE_MARKS)
+    step, fin = _programs(backend, comm, const, state)
+    obs_trace.count("program_cache_hits",
+                    sum(p.tally is not None for p in (step, fin)))
 
     # The progress half of stopping_rule doubles as the no-progress
     # guard: if the threshold cannot remove anything (e.g. the truncation
@@ -426,52 +514,43 @@ def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
     # spinning to max_rounds.
     rounds = 0
     prev_n = math.inf
-    t_round, t_fin = WireTally(), WireTally()
     round_walls = []
     compile_round = compile_fin = fin_wall = None
+    run_step, run_fin = step.fn, fin.fn
     if trace is not None:
         trace.meta.setdefault("eta", const.eta)
         trace.meta.setdefault("k", const.k)
         trace.meta.setdefault("max_rounds", const.max_rounds)
-        # AOT-compile both programs up front so each round's wall_s is
-        # pure execution and compile_s is split out. The lowering traces
-        # the collectives, so it MUST run inside the same wire tally the
-        # first inline call would have recorded into; on backends without
-        # a working .lower the fallback leaves compile inline (absorbed
-        # into round 1's wall, exactly the untraced behavior). Each
-        # record's wall and compile time is its span's own interval.
-        with wire_tally(t_round), span("soccer.compile",
-                                       program="round") as sp:
-            step, compile_s = timed_compile(step, state)
-        compile_round = None if compile_s is None else sp.t1 - sp.t0
-        with wire_tally(t_fin), span("soccer.compile",
-                                     program="finalize") as sp:
-            fin, compile_s = timed_compile(fin, state)
-        compile_fin = None if compile_s is None else sp.t1 - sp.t0
+        # AOT-compile both programs up front (once per process and key)
+        # so each round's wall_s is pure execution and compile_s is split
+        # out; on backends without a working .lower the compile stays
+        # inline (absorbed into round 1's wall, exactly the untraced
+        # behavior). Each record's wall and compile time is its span's
+        # own interval.
+        compile_round = _compile_ahead(step, state, "round")
+        compile_fin = _compile_ahead(fin, state, "finalize")
+        run_step, run_fin = (p.fn if p.aot is None else p.aot
+                             for p in (step, fin))
     while rounds < const.max_rounds:
         n_live = int(obs_trace.to_host(state.n_remaining))
         if not stopping_rule(n_live, const.eta, prev_n):
             break
         prev_n = n_live
         if trace is None:
-            with wire_tally(t_round):   # records once, at the round's trace
-                state = step(state)
+            state = _traced(step, run_step, state)
         else:
             with span("soccer.round", round=rounds + 1) as sp:
-                with wire_tally(t_round):
-                    state = step(state)
+                state = _traced(step, run_step, state)
                 jax.block_until_ready(state.n_remaining)
             round_walls.append(sp.t1 - sp.t0)
         rounds += 1
         if on_round is not None:
             state = on_round(rounds, state) or state
     if trace is None:
-        with wire_tally(t_fin):
-            state = fin(state)
+        state = _traced(fin, run_fin, state)
     else:
         with span("soccer.finalize") as sp:
-            with wire_tally(t_fin):
-                state = fin(state)
+            state = _traced(fin, run_fin, state)
             jax.block_until_ready(state.centers)
         fin_wall = sp.t1 - sp.t0
 
@@ -485,6 +564,8 @@ def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
             n_rem = int(obs_trace.to_host(state.n_remaining))
     # achieved wire bytes: static per-trace payload + per-row widths of
     # the ragged channels x the realized row counts the state tracked
+    # (the programs' tallies from their last trace, this fit's or not)
+    t_round, t_fin = step.tally or WireTally(), fin.tally
     wire_payload = np.concatenate(
         [t_round.bytes_at(up[:rounds]),
          t_fin.bytes_at(up[rounds:rounds + 1])])

@@ -21,8 +21,9 @@ Design constraints (the tentpole contract):
 * **Per-run counters.** ``count(name, n)`` adds to the active trace's
   ``counters`` in either mode: the SOCCER path counts the bytes it moves
   between host and device (``h2d_bytes``/``d2h_bytes``, through
-  ``to_host``/``count_put``) and the programs it traces
-  (``programs_traced``).
+  ``to_host``/``count_put``), the programs it traces
+  (``programs_traced``) and those it finds already built
+  (``program_cache_hits``).
 
 The per-round record schema is pinned (``ROUND_SCHEMA``): field names
 and value types are part of the exported JSONL contract and covered by a

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from repro.api import fit
+from repro.core import soccer
 from repro.obs import REGISTRY, MetricsRegistry
 from repro.obs.export import (chrome_trace_events, load_jsonl, write_jsonl)
 from repro.obs.report import format_diff, format_summary, main as report_main
@@ -26,6 +27,13 @@ M, K = 4, 4
 def _data(seed=0, p=256, d=8):
     rng = np.random.default_rng(seed)
     return rng.normal(size=(M, p, d)).astype(np.float32)
+
+
+@pytest.fixture
+def fresh_programs():
+    """An empty SOCCER program cache, so the fit under test is the first
+    of its shapes in the process: it traces and compiles both programs."""
+    soccer.clear_program_cache()
 
 
 def _soccer(x, trace=None, seed=0):
@@ -86,7 +94,7 @@ def test_trace_off_allocates_nothing():
 # ------------------------------------------------------------ rounds mode
 
 
-def test_soccer_trace_wire_sum_and_stop_margin():
+def test_soccer_trace_wire_sum_and_stop_margin(fresh_programs):
     """Acceptance: the per-round records sum to the result's measured
     wire bytes, and the stopping-rule margin explains the round count —
     the first round whose post-removal live set fit the coordinator is
@@ -151,7 +159,7 @@ def _full_soccer(x):
     return res, res.extra["trace"]
 
 
-def test_traced_fit_spans_every_host_phase_in_order():
+def test_traced_fit_spans_every_host_phase_in_order(fresh_programs):
     res, t = _full_soccer(_data(p=2048))
     assert [s["name"] for s in t["spans"]] == (
         ["fit.place", "soccer.place", "soccer.compile", "soccer.compile"]
@@ -168,7 +176,7 @@ def test_traced_fit_spans_every_host_phase_in_order():
     assert 0 < covered <= t["wall_s"]
 
 
-def test_span_durations_are_the_records_walls():
+def test_span_durations_are_the_records_walls(fresh_programs):
     """One measurement, not two: each round, finalize and compile span's
     interval is its record's wall_s / compile_s."""
     res, t = _full_soccer(_data(p=2048))
@@ -183,7 +191,8 @@ def test_span_durations_are_the_records_walls():
 
 
 @pytest.mark.parametrize("on_device", [False, True])
-def test_fit_counts_programs_and_host_device_bytes(on_device):
+def test_fit_counts_programs_and_host_device_bytes(on_device,
+                                                 fresh_programs):
     x = _data(p=2048)
     xin = jax.numpy.asarray(x) if on_device else x
     res = fit(xin, K, algo="soccer", backend="virtual", epsilon=0.2,
@@ -198,6 +207,7 @@ def test_fit_counts_programs_and_host_device_bytes(on_device):
         state.centers, state.centers_valid, state.n_remaining))
     assert t["counters"] == {
         "programs_traced": 2,
+        "program_cache_hits": 0,
         "h2d_bytes": x.nbytes,
         "d2h_bytes": (x.nbytes if on_device else 0) + readback
                      + checks * state.n_remaining.nbytes}

@@ -118,7 +118,9 @@ class Backend(Protocol):
         """Comm object for ``m`` machines (VirtualCluster/MeshCluster)."""
 
     def put(self, tree: Any, marks: Any) -> Any:
-        """Place a pytree according to its marks (device_put on a mesh)."""
+        """Place a pytree according to its marks: on a mesh each device
+        receives only its shard of a host leaf; on one device the host
+        leaves are put there whole and device leaves stay as they are."""
 
     def compile(self, fn, in_marks: Tuple, out_marks: Any,
                 donate: Tuple[int, ...] = ()):
@@ -143,7 +145,7 @@ class VirtualBackend:
 
     def put(self, tree, marks):
         del marks
-        return tree
+        return jax.device_put(tree)     # host leaves to the one device
 
     def compile(self, fn, in_marks, out_marks, donate=()):
         del in_marks, out_marks
@@ -167,7 +169,7 @@ class CommBackend:
 
     def put(self, tree, marks):
         del marks
-        return tree
+        return jax.device_put(tree)     # host leaves to the one device
 
     def compile(self, fn, in_marks, out_marks, donate=()):
         del in_marks, out_marks

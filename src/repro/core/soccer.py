@@ -28,7 +28,7 @@ import numpy as np
 from jax import lax
 
 from repro.configs.soccer_paper import SoccerParams
-from repro.core.comm import WireTally, wire_tally
+from repro.core.comm import VirtualCluster, WireTally, wire_tally
 from repro.obs import trace as obs_trace
 from repro.obs.trace import current_trace, span, timed_compile
 from repro.core.kmeans import kmeans
@@ -136,24 +136,53 @@ class SoccerState(NamedTuple):
     alpha_hist: jax.Array    # (R,) realized P2 sampling rate per round
 
 
-def init_state(x_parts: jax.Array, const: SoccerConstants, key: jax.Array,
-               w: Optional[jax.Array] = None,
-               alive: Optional[jax.Array] = None) -> SoccerState:
-    local_m, p, d = x_parts.shape
+def _new_leaves(alive: Optional[jax.Array], *, comm,
+                const: SoccerConstants, p: int, d: int,
+                make_w: bool) -> SoccerState:
+    """The leaves of the state before round 1 that the caller does not
+    give, for ``comm.local_m`` machines of ``p`` points in R^d: ``x``,
+    ``key``, ``w`` unless ``make_w``, and a given ``alive`` are None.
+    Run under a backend, each machine's leaves are built on its own
+    device; ``n_remaining`` counts the live points of all machines."""
+    local_m = comm.local_m
     r = const.max_rounds + 1
-    w = jnp.ones((local_m, p), jnp.float32) if w is None else w
-    alive = jnp.ones((local_m, p), bool) if alive is None else alive
+    live = jnp.ones((local_m, p), bool) if alive is None else alive
     return SoccerState(
-        x=x_parts.astype(jnp.float32), w=w, alive=alive,
-        machine_ok=jnp.ones((local_m,), bool), key=key,
+        x=None, key=None,
+        w=jnp.ones((local_m, p), jnp.float32) if make_w else None,
+        alive=live if alive is None else None,
+        machine_ok=jnp.ones((local_m,), bool),
         round_idx=jnp.int32(0),
-        n_remaining=jnp.sum(alive).astype(jnp.int32),  # overwritten in mesh mode
+        n_remaining=comm.psum(jnp.sum(live, axis=1, dtype=jnp.int32)),
         centers=jnp.zeros((r, const.k_plus, d), jnp.float32),
         centers_valid=jnp.zeros((r, const.k_plus), bool),
         v_hist=jnp.zeros((r,), jnp.float32),
         n_hist=jnp.zeros((r,), jnp.int32),
         uplink=jnp.zeros((r,), jnp.int32),
         alpha_hist=jnp.zeros((r,), jnp.float32))
+
+
+def _with_given(state: SoccerState, x: jax.Array, key: jax.Array,
+                w: Optional[jax.Array], alive: Optional[jax.Array]
+                ) -> SoccerState:
+    """``_new_leaves``' state completed with the caller's arrays."""
+    state = state._replace(x=x.astype(jnp.float32), key=key)
+    if w is not None:
+        state = state._replace(w=w)
+    if alive is not None:
+        state = state._replace(alive=alive)
+    return state
+
+
+def init_state(x_parts: jax.Array, const: SoccerConstants, key: jax.Array,
+               w: Optional[jax.Array] = None,
+               alive: Optional[jax.Array] = None) -> SoccerState:
+    """The state before round 1, built eagerly where ``x_parts`` lives
+    (``run_soccer`` builds it under its backend instead)."""
+    m, p, d = x_parts.shape
+    state = _new_leaves(alive, comm=VirtualCluster(m), const=const, p=p,
+                        d=d, make_w=w is None)
+    return _with_given(state, x_parts, key, w, alive)
 
 
 def _blackbox(const: SoccerConstants, key: jax.Array, x: jax.Array,
@@ -346,6 +375,10 @@ STATE_MARKS = SoccerState(
     centers_valid="rep", v_hist="rep", n_hist="rep", uplink="rep",
     alpha_hist="rep")
 
+# Placement marks for run_soccer's inputs (x, w, alive, key): the caller's
+# arrays are put first, so each device receives only its machines' rows.
+INPUT_MARKS = ("machine", "machine", "machine", "rep")
+
 
 def effective_n(m: int, p: int, w: Optional[jax.Array],
                 alive: Optional[jax.Array]) -> int:
@@ -380,11 +413,11 @@ def stopping_rule(remaining: float, capacity: float, prev: float) -> bool:
 
 # ------------------------------------------------------ the program cache
 
-# SOCCER's round and finalize programs, built once per process and key
-# (``_program_key``): a fit whose key is here traces nothing. LRU, so a
-# sweep over many shapes holds at most this many pairs.
+# SOCCER's init, round and finalize programs, built once per process and
+# key (``_program_key``): a fit whose key is here traces nothing. LRU, so
+# a sweep over many shapes holds at most this many triples.
 _PROGRAM_CACHE_SIZE = 8
-_PROGRAMS: "collections.OrderedDict[tuple, Tuple[_Program, _Program]]" = (
+_PROGRAMS: "collections.OrderedDict[tuple, Tuple[_Program, ...]]" = (
     collections.OrderedDict())
 
 
@@ -403,24 +436,27 @@ def clear_program_cache() -> None:
 
 
 def _program_key(backend, comm, const: SoccerConstants,
-                 state: SoccerState) -> tuple:
-    """Everything that decides what tracing the two programs produces:
-    the placement, the constants, every state leaf's aval and sharding,
-    and the trace-time kernel settings."""
-    leaves = tuple((jnp.shape(a), jnp.result_type(a),
+                 inputs: tuple) -> tuple:
+    """Everything that decides what tracing the three programs produces:
+    the placement, the constants, the aval and sharding of every placed
+    input ``(x, w, alive, key)`` (None where not given), which fix those
+    of the state, and the trace-time kernel settings."""
+    leaves = tuple(None if a is None else
+                   (jnp.shape(a), jnp.result_type(a),
                     getattr(a, "weak_type", False),
                     getattr(a, "sharding", None))
-                   for a in jax.tree.leaves(state))
+                   for a in inputs)
     return (backend, comm, const, leaves, ops._backend(None),
             autotune_mode())
 
 
-def _programs(backend, comm, const: SoccerConstants, state: SoccerState
-              ) -> Tuple[_Program, _Program]:
-    """The (round, finalize) programs for this fit: those an earlier fit
-    with the same key built, else new ones, cached unless part of the key
-    cannot be hashed (a caller-supplied comm, say)."""
-    key = _program_key(backend, comm, const, state)
+def _programs(backend, comm, const: SoccerConstants, inputs: tuple
+              ) -> Tuple[_Program, _Program, _Program]:
+    """The (init, round, finalize) programs for this fit's placed inputs
+    ``(x, w, alive, key)``: those an earlier fit with the same key built,
+    else new ones, cached unless part of the key cannot be hashed (a
+    caller-supplied comm, say). Init runs ``_new_leaves`` on ``alive``."""
+    key = _program_key(backend, comm, const, inputs)
     try:
         progs = _PROGRAMS.get(key)
     except TypeError:
@@ -428,7 +464,16 @@ def _programs(backend, comm, const: SoccerConstants, state: SoccerState
     if progs is not None:
         _PROGRAMS.move_to_end(key)
         return progs
-    progs = tuple(_Program(backend.compile(
+    x, w, alive, _ = inputs
+    _, p, d = x.shape
+    init = _Program(backend.compile(
+        functools.partial(_new_leaves, comm=comm, const=const, p=p, d=d,
+                          make_w=w is None),
+        (None if alive is None else "machine",),
+        STATE_MARKS._replace(x=None, key=None,
+                             w="machine" if w is None else None,
+                             alive="machine" if alive is None else None)))
+    progs = (init,) + tuple(_Program(backend.compile(
         functools.partial(body, comm=comm, const=const),
         (STATE_MARKS,), STATE_MARKS))
         for body in (soccer_round, soccer_finalize))
@@ -437,6 +482,22 @@ def _programs(backend, comm, const: SoccerConstants, state: SoccerState
         if len(_PROGRAMS) > _PROGRAM_CACHE_SIZE:
             _PROGRAMS.popitem(last=False)
     return progs
+
+
+def _place(backend, comm, const: SoccerConstants, given: tuple
+           ) -> Tuple[Tuple[_Program, _Program, _Program], SoccerState]:
+    """Put the caller's ``(x, w, alive, key)`` on the devices, each
+    machine's rows on its own, and build the rest of the state there with
+    the init program; returns the fit's programs and its first state.
+    Only the state holds the placed arrays once this returns, so a round's
+    copy of ``x`` replaces it rather than adding to it."""
+    obs_trace.count_put(*given[:3])
+    placed = backend.put(given, tuple(
+        None if a is None else mk for a, mk in zip(given, INPUT_MARKS)))
+    obs_trace.count_placement(given, placed)
+    progs = _programs(backend, comm, const, placed)
+    x, w, alive, key = placed
+    return progs, _with_given(progs[0].fn(alive), x, key, w, alive)
 
 
 def _traced(prog: _Program, fn, *args):
@@ -497,13 +558,10 @@ def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
     trace = current_trace()
     obs_trace.count("programs_traced", 0)   # reported on a cache hit too
     with span("soccer.place"):
-        obs_trace.count_put(x_parts, w, alive)
-        state = init_state(jnp.asarray(x_parts), const, key, w=w,
-                           alive=alive)
-        state = backend.put(state, STATE_MARKS)
+        (_, step, fin), state = _place(backend, comm, const,
+                                       (x_parts, w, alive, key))
         if trace is not None:   # the copy lands here, not in round 1
             jax.block_until_ready(state)
-    step, fin = _programs(backend, comm, const, state)
     obs_trace.count("program_cache_hits",
                     sum(p.tally is not None for p in (step, fin)))
 

@@ -21,7 +21,9 @@ Design constraints (the tentpole contract):
 * **Per-run counters.** ``count(name, n)`` adds to the active trace's
   ``counters`` in either mode: the SOCCER path counts the bytes it moves
   between host and device (``h2d_bytes``/``d2h_bytes``, through
-  ``to_host``/``count_put``), the programs it traces
+  ``to_host``/``count_put``), the most bytes any one device receives
+  while its input is placed (``place_max_device_bytes``, through
+  ``count_placement``), the programs it traces
   (``programs_traced``) and those it finds already built
   (``program_cache_hits``).
 
@@ -323,6 +325,38 @@ def count_put(*arrays) -> None:
     for a in arrays:
         if isinstance(a, np.ndarray):
             _STACK[-1].count("h2d_bytes", a.nbytes)
+
+
+def _extent(index, shape) -> List[range]:
+    """The rows of each axis that a shard ``index`` (slices) covers."""
+    return [range(*s.indices(n)) for s, n in zip(index, shape)]
+
+
+def count_placement(src, dst) -> None:
+    """Count as ``place_max_device_bytes`` of the active trace the most
+    bytes any one device receives when the leaves of ``src`` are placed
+    as the matching leaves of ``dst`` (device arrays): its shard of each
+    host leaf, and each shard of a device leaf that it did not already
+    hold. Computed from the shardings, so exact on any backend; arrays a
+    program creates on the device are not placements. Call it once per
+    placement: counts add, and a sum of maxima is no maximum."""
+    if not _STACK:
+        return
+    from jax import Array, tree
+    received: Dict[Any, int] = collections.Counter()
+    for a, b in zip(tree.leaves(src), tree.leaves(dst)):
+        held = (a.sharding.devices_indices_map(a.shape)
+                if isinstance(a, Array) else {})
+        for dev, index in b.sharding.addressable_devices_indices_map(
+                b.shape).items():
+            want = _extent(index, b.shape)
+            have = _extent(held[dev], b.shape) if dev in held else None
+            if have is None or any(w.start < h.start or w.stop > h.stop
+                                   for w, h in zip(want, have)):
+                received[dev] += (int(np.prod([len(r) for r in want]))
+                                  * b.dtype.itemsize)
+    _STACK[-1].count("place_max_device_bytes",
+                     max(received.values(), default=0))
 
 
 def event(name: str, **attrs) -> None:

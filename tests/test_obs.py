@@ -84,6 +84,7 @@ def test_trace_off_allocates_nothing():
     res = _soccer(x)
     obs_trace.count("programs_traced")
     obs_trace.count_put(x)
+    obs_trace.count_placement(x, jax.numpy.asarray(x))
     obs_trace.to_host(jax.numpy.zeros(3))
     with obs_trace.span("off"):
         pass
@@ -209,6 +210,7 @@ def test_fit_counts_programs_and_host_device_bytes(on_device,
         "programs_traced": 2,
         "program_cache_hits": 0,
         "h2d_bytes": x.nbytes,
+        "place_max_device_bytes": x.nbytes,   # one device takes all of x
         "d2h_bytes": (x.nbytes if on_device else 0) + readback
                      + checks * state.n_remaining.nbytes}
     line = REGISTRY.read("api.fit.traces")["api.fit.traces"]["events"][-1]

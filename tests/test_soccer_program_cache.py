@@ -126,17 +126,17 @@ def test_cache_holds_at_most_its_size():
         const = soccer.derive_constants(M * 64, 64,
                                         SoccerParams(k=k, epsilon=0.2,
                                                      n_machines=M))
-        state = soccer.init_state(x, const, jax.random.PRNGKey(0))
-        built.append((const, state, soccer._programs(backend, comm, const,
-                                                     state)))
+        inputs = (x, None, None, jax.random.PRNGKey(0))
+        built.append((const, inputs, soccer._programs(backend, comm, const,
+                                                      inputs)))
         assert len(soccer._PROGRAMS) <= soccer._PROGRAM_CACHE_SIZE
     assert len(soccer._PROGRAMS) == soccer._PROGRAM_CACHE_SIZE
     # least recently used goes first: the oldest were dropped, the newest
     # come back as the same objects
-    const, state, progs = built[-1]
-    assert soccer._programs(backend, comm, const, state) is progs
-    const, state, progs = built[0]
-    assert soccer._programs(backend, comm, const, state) is not progs
+    const, inputs, progs = built[-1]
+    assert soccer._programs(backend, comm, const, inputs) is progs
+    const, inputs, progs = built[0]
+    assert soccer._programs(backend, comm, const, inputs) is not progs
 
 
 class _UnhashableCluster(VirtualCluster):
@@ -171,7 +171,7 @@ def test_jax_retrace_of_a_cached_program_keeps_wire_bytes():
     the fit's wire bytes are those of one trace, not two."""
     x = _data(p=2048)
     first = _fit(x, seed=1, trace=None)
-    (step, fin), = soccer._PROGRAMS.values()
+    (_, step, fin), = soccer._PROGRAMS.values()
     tallies = step.tally, fin.tally
     jax.clear_caches()
     again = _fit(x, seed=1, trace=None)

@@ -8,7 +8,9 @@ same, jit-compatibly:
    ``total`` across machines proportionally to their live counts
    (deterministic, replicated on every machine).
 2. per-machine Gumbel top-k draws ``c_j`` live points uniformly without
-   replacement (static cap, dynamic count).
+   replacement (static cap, dynamic count), by an exact threshold select
+   (``top_c_indices``) that takes ``lax.top_k``'s indices in its order
+   without sorting all of a machine's points.
 3. ``comm.gather_ragged`` — the length-prefixed ragged upload: machine j
    contributes exactly its ``c_j`` drawn rows, landing at offset
    ``sum(c[:j])`` of the global ``(rows, d)`` buffer. Payload is exactly
@@ -102,6 +104,104 @@ def exclusive_cumsum(c: jax.Array) -> jax.Array:
     return jnp.concatenate([jnp.zeros((1,), c.dtype), jnp.cumsum(c)[:-1]])
 
 
+# The select lays a machine's keys out as (p / _LANES, _LANES) blocks and
+# resolves the ranks 1..c in steps of _RANKS, so a draw's work after its
+# threshold grows with c and not with the buffer width cap.
+_LANES = 128
+_RANKS = 1024
+
+
+def _count_below(ends: jax.Array, r: jax.Array) -> jax.Array:
+    """``#{i : ends[i] < r}`` for each ``r``, ``ends`` (n,) non-decreasing:
+    a compare against every ``_LANES``-th end, then one row gather of
+    ``_LANES`` ends per ``r`` (no gather per step of a binary search)."""
+    n = ends.shape[0]
+    rows = -(-n // _LANES)
+    e2 = jnp.pad(ends, (0, rows * _LANES - n),
+                 constant_values=jnp.iinfo(jnp.int32).max
+                 ).reshape(rows, _LANES)
+    s = jnp.sum(e2[None, :, -1] < r[:, None], axis=1)
+    s = jnp.minimum(s, rows - 1)
+    return s * _LANES + jnp.sum(e2[s] < r[:, None], axis=1)
+
+
+def top_c_indices(scores: jax.Array, c: jax.Array, cap: int) -> jax.Array:
+    """``lax.top_k(scores, cap)``'s first ``c`` indices, without sorting
+    all of ``scores``.
+
+    Args:
+      scores: (p,) float32; live scores >= 0, dead ones < 0.
+      c: scalar int32, at most the number of live scores.
+      cap: static buffer width.
+
+    Returns:
+      (cap,) int32: the first ``c`` entries index the ``c`` largest
+      scores, largest first and equal scores lower index first (top_k's
+      order); the entries from ``c`` on lie in [0, p), otherwise
+      arbitrary.
+    """
+    p = scores.shape[0]
+    nb = -(-p // _LANES)
+    # non-negative float32s order as their int32 bit patterns; dead and
+    # padding keys are < 0
+    flat = lax.bitcast_convert_type(scores, jnp.int32)
+    keys = jnp.pad(flat, (0, nb * _LANES - p),
+                   constant_values=-1).reshape(nb, _LANES)
+    # t = the c-th largest key: the largest t with #(keys >= t) >= c, set
+    # one bit at a time from the top (c = 0 sets every bit)
+    t = jnp.int32(0)
+    for bit in range(30, -1, -1):
+        cand = t | (1 << bit)
+        t = jnp.where(jnp.sum(keys >= cand) >= c, cand, t)
+    # members: every key above t, then the keys equal to t up to index
+    # i_tie, where the (c - #(> t))-th of them lies
+    above, at = keys > t, keys == t
+    need = c - jnp.sum(above)
+    at_n = jnp.sum(at, axis=1)
+    at_end = jnp.cumsum(at_n)
+    tb = jnp.minimum(_count_below(at_end, need[None])[0], nb - 1)
+    at_row = jnp.cumsum(at[tb].astype(jnp.int32)) + at_end[tb] - at_n[tb]
+    i_tie = jnp.where(need > 0, tb * _LANES + jnp.sum(at_row < need), -1)
+    pos = jnp.arange(nb * _LANES, dtype=jnp.int32).reshape(nb, _LANES)
+    member = above | (at & (pos <= i_tie))
+    # each position's running member count: within its block by a
+    # triangular matmul (0/1 products, sums <= 128: exact), then the
+    # blocks' ends; packed beside the keys so one row gather serves a rank
+    tri = jnp.arange(_LANES)[:, None] <= jnp.arange(_LANES)[None, :]
+    within = jnp.dot(member.astype(jnp.bfloat16), tri.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32).astype(jnp.int32)
+    ends = jnp.cumsum(within[:, -1])
+    packed = jnp.concatenate(
+        [within + (ends - within[:, -1])[:, None], keys], axis=1)
+    lanes = jnp.arange(_LANES, dtype=jnp.int32)
+    steps = -(-cap // _RANKS)
+
+    def step(state):
+        # the r-th member lies in the first block whose end reaches r, at
+        # the first lane whose running count reaches r
+        k, idx_buf, key_buf = state
+        r = k * _RANKS + jnp.arange(1, _RANKS + 1, dtype=jnp.int32)
+        b = jnp.minimum(_count_below(ends, r), nb - 1)
+        rows = packed[b]
+        lane = jnp.minimum(
+            jnp.sum(rows[:, :_LANES] < r[:, None], axis=1), _LANES - 1)
+        got = jnp.sum(jnp.where(lanes == lane[:, None], rows[:, _LANES:], 0),
+                      axis=1)
+        idx = jnp.minimum(b * _LANES + lane, p - 1)
+        # sort key: keys descending, the slots past c (1) last
+        order = jnp.where(r <= c, -got, 1)
+        return (k + 1,
+                lax.dynamic_update_slice(idx_buf, idx, (k * _RANKS,)),
+                lax.dynamic_update_slice(key_buf, order, (k * _RANKS,)))
+
+    _, idx, key = lax.while_loop(
+        lambda state: (state[0] < steps) & (state[0] * _RANKS < c), step,
+        (jnp.int32(0), jnp.full((steps * _RANKS,), p - 1, jnp.int32),
+         jnp.ones((steps * _RANKS,), jnp.int32)))
+    # members arrive in index order; the stable sort keeps equal keys in it
+    return lax.sort((key[:cap], idx[:cap]), num_keys=1, is_stable=True)[1]
+
+
 def sample_local(key: jax.Array, alive: jax.Array, c: jax.Array,
                  cap: int) -> Tuple[jax.Array, jax.Array]:
     """Draw ``c`` live points uniformly without replacement (Gumbel top-k).
@@ -119,9 +219,7 @@ def sample_local(key: jax.Array, alive: jax.Array, c: jax.Array,
     p = alive.shape[0]
     g = jax.random.uniform(key, (p,), minval=1e-7, maxval=1.0)
     scores = jnp.where(alive, g, -1.0)
-    _, idx = lax.top_k(scores, min(cap, p))
-    if cap > p:  # degenerate tiny-machine case
-        idx = jnp.pad(idx, (0, cap - p))
+    idx = top_c_indices(scores, c, cap)
     take = jnp.arange(cap, dtype=jnp.int32) < c
     return idx.astype(jnp.int32), take
 

@@ -10,8 +10,8 @@ Algorithms register drivers in ``repro.api.registry``; backends
 (virtual single-device, mesh shard_map) implement the ``Backend``
 protocol in ``repro.api.backends``.
 """
-from repro.api.backends import (Backend, CommBackend, MeshBackend,
-                                VirtualBackend, resolve_backend)
+from repro.api.backends import (Backend, MeshBackend, VirtualBackend,
+                                resolve_backend)
 from repro.api.registry import (get_algorithm, list_algorithms,
                                 register_algorithm)
 from repro.api.result import ClusterResult, uplink_bytes
@@ -23,7 +23,7 @@ from repro.coresets import algorithms as _coreset_algorithms  # noqa: F401
 from repro import robust as _robust  # noqa: F401  (registers kzmeans)
 
 __all__ = [
-    "Backend", "ClusterResult", "CommBackend", "MeshBackend",
+    "Backend", "ClusterResult", "MeshBackend",
     "VirtualBackend", "fit", "fit_update", "get_algorithm",
     "list_algorithms",
     "register_algorithm", "resolve_backend", "uplink_bytes",

@@ -20,11 +20,14 @@ import numpy as np
 from repro.api.registry import register_algorithm
 from repro.api.result import ClusterResult, uplink_bytes
 from repro.configs.soccer_paper import SoccerParams
+from repro.core import programs
 from repro.core.eim11 import run_eim11
 from repro.core.kmeans import kmeans
 from repro.core.kmeans_parallel import run_kmeans_parallel
 from repro.core.minibatch import minibatch_kmeans
+from repro.core.sampling import quantize_uplink
 from repro.core.soccer import run_soccer
+from repro.obs.trace import clock, current_trace
 
 _SOCCER_FIELDS = {f.name for f in dataclasses.fields(SoccerParams)}
 
@@ -133,12 +136,27 @@ def fit_eim11(x_parts, k: int, *, backend, key=None, w=None, alive=None,
         extra={"broadcast_points": res.broadcast_points, "raw": res})
 
 
+def _central(kk, xp, wp, *, comm, method: str, k: int, dtype: str,
+             wire: str, bb_kw: tuple):
+    """Every machine's shard to the coordinator, then the black box on the
+    union."""
+    if wire == "codes":
+        # int8 codes + per-machine qparams on the wire, dequantized
+        # on arrival (1 byte/coordinate actually moves)
+        xa = comm.concat_machines_compressed(xp)
+    else:
+        xa = quantize_uplink(comm.concat_machines(xp), dtype)
+    wa = comm.concat_machines(wp, meta=True)
+    if method == "minibatch":
+        return minibatch_kmeans(kk, xa, wa, k, **dict(bb_kw))
+    return kmeans(kk, xa, wa, k, **dict(bb_kw))
+
+
 def _fit_central(method: str, x_parts, k, backend, key, w, alive, seed,
                  **bb_kw) -> ClusterResult:
     """Centralized baseline: every machine uploads its full shard once,
     the coordinator runs the black box on the union."""
     m, p, d = x_parts.shape
-    comm = backend.make_comm(m)
     x = backend.put(jnp.asarray(x_parts, jnp.float32), "machine")
     w_np = np.ones((m, p), np.float32) if w is None else np.asarray(
         w, np.float32)
@@ -147,39 +165,20 @@ def _fit_central(method: str, x_parts, k, backend, key, w, alive, seed,
     w_dev = backend.put(jnp.asarray(w_np), "machine")
     key = jax.random.PRNGKey(seed) if key is None else key
 
-    wire = _uplink_wire(backend)
-
-    def central(kk, xp, wp):
-        from repro.api.backends import quantize_uplink
-        if wire == "codes":
-            # int8 codes + per-machine qparams on the wire, dequantized
-            # on arrival (1 byte/coordinate actually moves)
-            xa = comm.concat_machines_compressed(xp)
-        else:
-            xa = quantize_uplink(comm.concat_machines(xp),
-                                 _uplink_dtype(backend))
-        wa = comm.concat_machines(wp, meta=True)
-        if method == "minibatch":
-            return minibatch_kmeans(kk, xa, wa, k, **bb_kw)
-        return kmeans(kk, xa, wa, k, **bb_kw)
-
-    from repro.core.comm import WireTally, wire_tally
-    from repro.obs.trace import clock, current_trace, timed_compile
-    fn = backend.compile(central, ("rep", "machine", "machine"),
-                         ("rep", "rep"))
-    t = WireTally()
+    fn, compile_s = programs.build(
+        method, "central", _central, backend, ("rep", "machine", "machine"),
+        ("rep", "rep"), (key, x, w_dev), static=dict(
+            comm=backend.make_comm(m), method=method, k=k,
+            dtype=_uplink_dtype(backend), wire=_uplink_wire(backend),
+            bb_kw=tuple(sorted(bb_kw.items()))))
     trace = current_trace()
-    if trace is None:
-        with wire_tally(t):
-            centers, cost = fn(key, x, w_dev)
-        wall_s = compile_s = None
-    else:
-        with wire_tally(t):
-            fn, compile_s = timed_compile(fn, key, x, w_dev)
-            t0 = clock()
-            centers, cost = fn(key, x, w_dev)
-            jax.block_until_ready(centers)
-            wall_s = clock() - t0
+    t0 = clock() if trace is not None else 0.0
+    centers, cost = fn(key, x, w_dev)
+    wall_s = None
+    if trace is not None:
+        jax.block_until_ready(centers)
+        wall_s = clock() - t0
+    t = fn.tally
     n_up = int(np.sum(w_np > 0))
     up = np.asarray([n_up], np.int64)
     if trace is not None:

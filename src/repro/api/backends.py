@@ -16,10 +16,10 @@ sharded on a mesh) or ``REPLICATED`` (identical on every machine). The
 backend translates marks into PartitionSpecs (mesh) or ignores them
 (virtual) — the same driver loop then runs unchanged in both modes.
 
-The backends are frozen dataclasses, so they hash by value: SOCCER's
-driver keys its cache of compiled programs on the backend and its comm,
-and a backend or comm that cannot be hashed gets programs built anew
-on every fit.
+The backends are frozen dataclasses, so they hash by value:
+``core.programs`` keys its cache of compiled programs on the backend and
+its comm, and a backend or comm that cannot be hashed gets programs
+built anew on every fit.
 """
 from __future__ import annotations
 
@@ -33,9 +33,6 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.comm import MeshCluster, VirtualCluster
-from repro.core.sampling import quantize_uplink  # noqa: F401  (the one
-# shared payload-rounding helper; re-exported here because backends own
-# the uplink_dtype contract)
 
 # Marks for the leaves of compiled-function argument/result pytrees.
 MACHINE = "machine"        # (local_m, ...) leading machine axis
@@ -107,8 +104,9 @@ class Backend(Protocol):
     Backends may additionally carry ``uplink_dtype`` (one of
     ``UPLINK_DTYPES``) — drivers read it with ``getattr(backend,
     "uplink_dtype", "float32")``, quantize upload payloads with
-    ``quantize_uplink`` and account ``ClusterResult.uplink_bytes`` at
-    that width — and ``uplink_wire`` (one of ``UPLINK_WIRES``), read via
+    ``core.sampling.quantize_uplink`` and account
+    ``ClusterResult.uplink_bytes`` at that width — and ``uplink_wire``
+    (one of ``UPLINK_WIRES``), read via
     ``check_uplink_wire(getattr(backend, "uplink_wire", "auto"), dtype)``
     to pick the codes vs values transport of ``core.comm``.
     """
@@ -142,30 +140,6 @@ class VirtualBackend:
 
     def make_comm(self, m: int) -> VirtualCluster:
         return VirtualCluster(m)
-
-    def put(self, tree, marks):
-        del marks
-        return jax.device_put(tree)     # host leaves to the one device
-
-    def compile(self, fn, in_marks, out_marks, donate=()):
-        del in_marks, out_marks
-        return jax.jit(fn, donate_argnums=donate)
-
-
-@dataclasses.dataclass(frozen=True)
-class CommBackend:
-    """Legacy adapter: run with a caller-supplied comm object, plain jit.
-
-    Kept so the pre-facade ``comm=`` keyword of the core drivers keeps
-    working; new code should pass a backend instead.
-    """
-    comm: Any
-    name: str = "virtual"
-    uplink_dtype: str = "float32"
-    uplink_wire: str = "auto"
-
-    def make_comm(self, m: int):
-        return self.comm
 
     def put(self, tree, marks):
         del marks

@@ -23,11 +23,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import programs
+from repro.core.comm import WireTally
 from repro.core.kmeans import kmeans
 from repro.core.metrics import assignment_counts
 from repro.core.reduce import reduce_to_k
 from repro.core.sampling import draw_global_sample
 from repro.kernels import ops
+from repro.obs.trace import clock, current_trace
 
 
 @dataclasses.dataclass
@@ -54,17 +57,70 @@ def _weighted_quantile(d2: jax.Array, w: jax.Array, q: float) -> jax.Array:
     return d2[order][jnp.minimum(idx, d2.shape[0] - 1)]
 
 
+def _round(kk, x, w, alive, centers, valid, base, *, comm, s: int, cap: int,
+           rows: int, remove_frac: float, uplink_dtype: str,
+           uplink_wire: str):
+    """One EIM11 round: two uploads, the first joins the clustering, the
+    second sets the removal quantile."""
+    n_local = jnp.sum(alive, axis=1).astype(jnp.int32)
+    n_vec = comm.all_machines(n_local)
+    k1, k2 = jax.random.split(kk)
+    s1, _, r1 = draw_global_sample(comm, k1, x, w, alive, n_vec, s, cap,
+                                   upload_dtype=uplink_dtype,
+                                   wire=uplink_wire)
+    s2, w2, r2 = draw_global_sample(comm, k2, x, w, alive, n_vec, s, cap,
+                                    upload_dtype=uplink_dtype,
+                                    wire=uplink_wire)
+    # coordinator adds the whole first sample to the clustering (the
+    # clustering buffer is broadcast DOWNlink, so it stays f32; only
+    # the uplink payload s1/s2 may arrive narrowed)
+    centers = jax.lax.dynamic_update_slice(
+        centers, s1.astype(jnp.float32), (base, 0))
+    row_ids = jnp.arange(rows)
+    valid = valid | ((row_ids >= base) & (row_ids < base + s))
+    # quantile threshold from the second sample
+    d2s, _ = ops.min_dist(s2, centers, valid)
+    v = _weighted_quantile(d2s, w2, remove_frac)
+    # machines: remove everything within the threshold
+    d2x = jax.vmap(lambda xx: ops.min_dist(xx, centers, valid)[0])(x)
+    alive = alive & (d2x > v)
+    n_rem = comm.psum(jnp.sum(alive, axis=1).astype(jnp.int32))
+    return alive, centers, valid, n_rem, r1 + r2
+
+
+def _finalize(kk, x, w, alive, centers, valid, base, *, comm, s: int,
+              cap: int, rows: int, k: int, uplink_dtype: str,
+              uplink_wire: str):
+    """Survivors to the coordinator, k-means on them, then the weighted
+    reduction of the whole clustering to k."""
+    n_local = jnp.sum(alive, axis=1).astype(jnp.int32)
+    n_vec = comm.all_machines(n_local)
+    kf1, kf2 = jax.random.split(kk)
+    v_pts, v_w, real = draw_global_sample(comm, kf1, x, w, alive, n_vec, s,
+                                          cap, upload_dtype=uplink_dtype,
+                                          wire=uplink_wire)
+    c_fin, _ = kmeans(kf2, v_pts, v_w, k)
+    centers = jax.lax.dynamic_update_slice(centers, c_fin, (base, 0))
+    row_ids = jnp.arange(rows)
+    valid = valid | ((row_ids >= base) & (row_ids < base + k))
+    counts = assignment_counts(comm, x, w, centers, valid)
+    final = reduce_to_k(kf2, centers, counts * valid, k)
+    return final, real
+
+
+# (key, x, w, alive, centers, valid, base): both programs' arguments
+_IN_MARKS = ("rep", "machine", "machine", "machine", "rep", "rep", "rep")
+
+
 def run_eim11(x_parts: jax.Array, k: int, epsilon: float, *,
               delta: float = 0.1, remove_frac: float = 0.5,
               w: Optional[jax.Array] = None,
               alive: Optional[jax.Array] = None,
-              comm=None, backend=None,
+              backend=None,
               key: Optional[jax.Array] = None, max_rounds: int = 12,
               seed: int = 0) -> EIM11Result:
-    from repro.api.backends import CommBackend, resolve_backend
+    from repro.api.backends import check_uplink_wire, resolve_backend
     m, p, d = x_parts.shape
-    if backend is None and comm is not None:
-        backend = CommBackend(comm)
     backend = resolve_backend(backend, m)
     comm = backend.make_comm(m)
 
@@ -83,96 +139,41 @@ def run_eim11(x_parts: jax.Array, k: int, epsilon: float, *,
     w = jnp.ones((m, p), jnp.float32) if w is None else jnp.asarray(
         w, jnp.float32)
     w = backend.put(w, "machine")
-    alive_dev = backend.put(alive0, "machine")
-    cap = min(p, s)
+    alive = backend.put(alive0, "machine")
     uplink_dtype = getattr(backend, "uplink_dtype", "float32")
-    from repro.api.backends import check_uplink_wire
-    uplink_wire = check_uplink_wire(
-        getattr(backend, "uplink_wire", "auto"), uplink_dtype)
-    rows = max_rounds * s
+    static = dict(comm=comm, s=s, cap=min(p, s), rows=max_rounds * s,
+                  uplink_dtype=uplink_dtype,
+                  uplink_wire=check_uplink_wire(
+                      getattr(backend, "uplink_wire", "auto"),
+                      uplink_dtype))
     key = jax.random.PRNGKey(seed) if key is None else key
 
-    def round_fn(kk, x, w, alive, centers, valid, base):
-        n_local = jnp.sum(alive, axis=1).astype(jnp.int32)
-        n_vec = comm.all_machines(n_local)
-        k1, k2 = jax.random.split(kk)
-        s1, _, r1 = draw_global_sample(comm, k1, x, w, alive, n_vec, s,
-                                       cap, upload_dtype=uplink_dtype,
-                                       wire=uplink_wire)
-        s2, w2, r2 = draw_global_sample(comm, k2, x, w, alive, n_vec, s,
-                                        cap, upload_dtype=uplink_dtype,
-                                        wire=uplink_wire)
-        # coordinator adds the whole first sample to the clustering (the
-        # clustering buffer is broadcast DOWNlink, so it stays f32; only
-        # the uplink payload s1/s2 may arrive narrowed)
-        centers = jax.lax.dynamic_update_slice(
-            centers, s1.astype(jnp.float32), (base, 0))
-        row_ids = jnp.arange(rows)
-        valid = valid | ((row_ids >= base) & (row_ids < base + s))
-        # quantile threshold from the second sample
-        d2s, _ = ops.min_dist(s2, centers, valid)
-        v = _weighted_quantile(d2s, w2, remove_frac)
-        # machines: remove everything within the threshold
-        d2x = jax.vmap(lambda xx: ops.min_dist(xx, centers, valid)[0])(x)
-        alive = alive & (d2x > v)
-        n_rem = comm.psum(jnp.sum(alive, axis=1).astype(jnp.int32))
-        return alive, centers, valid, n_rem, r1 + r2
+    def program(name, body, out_marks, inputs, **more):
+        return programs.build("eim11", name, body, backend, _IN_MARKS,
+                              out_marks, inputs, static=dict(static, **more))
 
-    def final_fn(kk, x, w, alive, centers, valid, base):
-        n_local = jnp.sum(alive, axis=1).astype(jnp.int32)
-        n_vec = comm.all_machines(n_local)
-        kf1, kf2 = jax.random.split(kk)
-        v_pts, v_w, real = draw_global_sample(comm, kf1, x, w, alive, n_vec,
-                                              s, cap,
-                                              upload_dtype=uplink_dtype,
-                                              wire=uplink_wire)
-        c_fin, _ = kmeans(kf2, v_pts, v_w, k)
-        centers = jax.lax.dynamic_update_slice(centers, c_fin, (base, 0))
-        row_ids = jnp.arange(rows)
-        valid = valid | ((row_ids >= base) & (row_ids < base + k))
-        counts = assignment_counts(comm, x, w, centers, valid)
-        final = reduce_to_k(kf2, centers, counts * valid, k)
-        return final, real
-
-    step = backend.compile(
-        round_fn,
-        ("rep", "machine", "machine", "machine", "rep", "rep", "rep"),
-        ("machine", "rep", "rep", "rep", "rep"))
-    finalize = backend.compile(
-        final_fn,
-        ("rep", "machine", "machine", "machine", "rep", "rep", "rep"),
-        ("rep", "rep"))
-
-    alive = alive_dev
-    centers = jnp.zeros((rows, d), jnp.float32)
-    valid = jnp.zeros((rows,), bool)
+    centers = jnp.zeros((static["rows"], d), jnp.float32)
+    valid = jnp.zeros((static["rows"],), bool)
     n_hist = [n]
     uplink = []
     rounds = 0
     broadcast = 0
     n_rem = n
-    from repro.core.comm import WireTally, wire_tally
-    from repro.obs.trace import clock, current_trace, timed_compile
-    t_round, t_fin = WireTally(), WireTally()
     trace = current_trace()
+    step = compile_round = fin_wall = None
     round_walls = []
-    compile_round = compile_fin = fin_wall = None
     if trace is not None:
         trace.meta.setdefault("capacity", s)
         trace.meta.setdefault("max_rounds", max_rounds)
-        # AOT inside the tallies: lowering is where the wire is recorded
-        with wire_tally(t_round):
-            step, compile_round = timed_compile(
-                step, key, x, w, alive, centers, valid, jnp.int32(0))
-        with wire_tally(t_fin):
-            finalize, compile_fin = timed_compile(
-                finalize, key, x, w, alive, centers, valid, jnp.int32(0))
     while n_rem > s and rounds < max_rounds:
         kk, key = jax.random.split(key)
+        inputs = (kk, x, w, alive, centers, valid, jnp.int32(rounds * s))
+        if step is None:
+            step, compile_round = program(
+                "round", _round, ("machine", "rep", "rep", "rep", "rep"),
+                inputs, remove_frac=remove_frac)
         t0 = clock() if trace is not None else 0.0
-        with wire_tally(t_round):
-            alive, centers, valid, n_rem_a, up = step(
-                kk, x, w, alive, centers, valid, jnp.int32(rounds * s))
+        alive, centers, valid, n_rem_a, up = step(*inputs)
         n_rem = int(n_rem_a)
         if trace is not None:
             round_walls.append(clock() - t0)
@@ -183,14 +184,17 @@ def run_eim11(x_parts: jax.Array, k: int, epsilon: float, *,
 
     # final: survivors -> coordinator -> k-means; then weighted reduction
     kf, key = jax.random.split(key)
-    base = min(rounds * s, rows - k)
+    inputs = (kf, x, w, alive, centers, valid,
+              jnp.int32(min(rounds * s, static["rows"] - k)))
+    finalize, compile_fin = program("finalize", _finalize, ("rep", "rep"),
+                                    inputs, k=k)
     t0 = clock() if trace is not None else 0.0
-    with wire_tally(t_fin):
-        final, real = finalize(kf, x, w, alive, centers, valid,
-                               jnp.int32(base))
+    final, real = finalize(*inputs)
     if trace is not None:
         jax.block_until_ready(final)
         fin_wall = clock() - t0
+    t_round = WireTally() if step is None else step.tally
+    t_fin = finalize.tally
     uplink.append(int(real))
     up_arr = np.asarray(uplink, np.int64)
     wire_payload = np.concatenate(

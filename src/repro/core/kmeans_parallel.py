@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import functools
 from typing import Optional
 
 import jax
@@ -29,11 +28,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.core import programs
 from repro.core.metrics import assignment_counts
 from repro.core.reduce import reduce_to_k
 from repro.core.sampling import (exclusive_cumsum, global_weighted_choice,
                                  quantize_uplink, scatter_at)
 from repro.kernels import ops
+from repro.obs.trace import clock, current_trace
 
 # How many times each traced body below has been traced (NOT called):
 # the regression tests assert the round body traces a constant number of
@@ -87,19 +88,47 @@ def _one_round(comm, l: float, cap: int, upload_dtype: str,
     return new_centers, new_valid, phi, jnp.sum(jnp.minimum(c_vec, cap))
 
 
+def _seed(kk, x, w, *, comm, rows: int):
+    """The first center, drawn by weight, in a ``(rows, d)`` buffer."""
+    c0 = global_weighted_choice(kk, comm, w, x)
+    centers = jnp.zeros((rows, x.shape[-1]), jnp.float32).at[0].set(c0)
+    valid = jnp.zeros((rows,), bool).at[0].set(True)
+    return centers, valid
+
+
+def _all_rounds(keys, bases, x, w, centers, valid, *, comm, l: float,
+                cap: int, upload_dtype: str):
+    """Every oversampling round in ONE lax.scan — a single device
+    dispatch for the whole seeding phase instead of ``rounds`` host
+    round-trips (and a single trace of the round body)."""
+    def body(carry, kb):
+        centers, valid = carry
+        kk, base = kb
+        centers, valid, phi, nsel = _one_round(comm, l, cap, upload_dtype,
+                                               kk, x, w, centers, valid,
+                                               base)
+        return (centers, valid), (phi, nsel)
+
+    (centers, valid), (phis, nsels) = lax.scan(
+        body, (centers, valid), (keys, bases))
+    return centers, valid, phis, nsels
+
+
+def _counts(x, w, centers, valid, *, comm):
+    return assignment_counts(comm, x, w, centers, valid)
+
+
 def run_kmeans_parallel(x_parts: jax.Array, k: int, rounds: int, *,
                         l: Optional[float] = None,
                         w: Optional[jax.Array] = None,
-                        comm=None, backend=None,
+                        backend=None,
                         key: Optional[jax.Array] = None,
                         lloyd_iters: int = 25,
                         oversample_slack: float = 3.0,
                         seed: int = 0) -> KMeansParallelResult:
     """Driver over any backend (virtual by default); x_parts is (m, p, d)."""
-    from repro.api.backends import CommBackend, resolve_backend
+    from repro.api.backends import resolve_backend
     m, p, d = x_parts.shape
-    if backend is None and comm is not None:
-        backend = CommBackend(comm)
     backend = resolve_backend(backend, m)
     comm = backend.make_comm(m)
 
@@ -112,65 +141,43 @@ def run_kmeans_parallel(x_parts: jax.Array, k: int, rounds: int, *,
     rows = 1 + rounds * cap
     key = jax.random.PRNGKey(seed) if key is None else key
 
-    def seed_init(kk, x, w):
-        c0 = global_weighted_choice(kk, comm, w, x)
-        centers = jnp.zeros((rows, d), jnp.float32).at[0].set(c0)
-        valid = jnp.zeros((rows,), bool).at[0].set(True)
-        return centers, valid
+    def program(name, body, marks, out_marks, inputs, donate=(), **static):
+        return programs.build("kmeans_parallel", name, body, backend, marks,
+                              out_marks, inputs, static=dict(comm=comm,
+                                                             **static),
+                              donate=donate)
 
-    round_fn = functools.partial(
-        _one_round, comm, l, cap,
-        getattr(backend, "uplink_dtype", "float32"))
-
-    def all_rounds(keys, bases, x, w, centers, valid):
-        """Every oversampling round in ONE lax.scan — a single device
-        dispatch for the whole seeding phase instead of ``rounds`` host
-        round-trips (and a single trace of the round body)."""
-        def body(carry, kb):
-            centers, valid = carry
-            kk, base = kb
-            centers, valid, phi, nsel = round_fn(kk, x, w, centers, valid,
-                                                 base)
-            return (centers, valid), (phi, nsel)
-
-        (centers, valid), (phis, nsels) = lax.scan(
-            body, (centers, valid), (keys, bases))
-        return centers, valid, phis, nsels
-
-    seed_fn = backend.compile(seed_init, ("rep", "machine", "machine"),
-                              ("rep", "rep"))
-    rounds_fn = backend.compile(
-        all_rounds,
-        ("rep", "rep", "machine", "machine", "rep", "rep"),
-        ("rep", "rep", "rep", "rep"),
-        donate=(4, 5))                      # centers/valid update in place
-    counts_fn = backend.compile(
-        lambda x, w, c, v: assignment_counts(comm, x, w, c, v),
-        ("machine", "machine", "rep", "rep"), "rep")
-
-    from repro.core.comm import WireTally, wire_tally
-    from repro.obs.trace import clock, current_trace
-    t_seed, t_body, t_counts = WireTally(), WireTally(), WireTally()
     trace = current_trace()
     k0, key = jax.random.split(key)
-    t0 = clock() if trace is not None else 0.0
-    with wire_tally(t_seed):
-        centers, valid = seed_fn(k0, x, w)
+    seed_fn, c_seed = program("seed", _seed, ("rep", "machine", "machine"),
+                              ("rep", "rep"), (k0, x, w), rows=rows)
+    centers, valid = seed_fn(k0, x, w)
     round_keys = jax.random.split(key, rounds + 1)
     key = round_keys[0]
     bases = jnp.int32(1) + jnp.arange(rounds, dtype=jnp.int32) * cap
-    with wire_tally(t_body):    # scan body traces ONCE -> one round's bytes
-        centers, valid, phis, nsels = rounds_fn(round_keys[1:], bases, x, w,
-                                                centers, valid)
+    inputs = (round_keys[1:], bases, x, w, centers, valid)
+    rounds_fn, c_rounds = program(
+        "rounds", _all_rounds,
+        ("rep", "rep", "machine", "machine", "rep", "rep"),
+        ("rep", "rep", "rep", "rep"), inputs,
+        donate=(4, 5),                      # centers/valid update in place
+        l=l, cap=cap, upload_dtype=getattr(backend, "uplink_dtype",
+                                           "float32"))
+    t0 = clock() if trace is not None else 0.0
+    centers, valid, phis, nsels = rounds_fn(*inputs)
     phi_hist = [float(p) for p in phis]
     sel_hist = [int(s) for s in nsels]
     scan_wall = (clock() - t0) if trace is not None else None
 
-    with wire_tally(t_counts):
-        counts = counts_fn(x, w, centers, valid)
+    counts_fn, c_counts = program("counts", _counts,
+                                  ("machine", "machine", "rep", "rep"),
+                                  "rep", (x, w, centers, valid))
+    counts = counts_fn(x, w, centers, valid)
     kf, key = jax.random.split(key)
     final = reduce_to_k(kf, centers, counts * valid, k, lloyd_iters)
 
+    t_seed, t_body, t_counts = (f.tally for f in (seed_fn, rounds_fn,
+                                                  counts_fn))
     # per-round achieved bytes: the scan body's (constant) traffic each
     # round; the seeding choice joins round 0, the weighing pass the last
     wire_payload = np.full((max(rounds, 1),), t_body.payload, np.int64)
@@ -193,7 +200,9 @@ def run_kmeans_parallel(x_parts: jax.Array, k: int, rounds: int, *,
                              if r <= len(sel_hist) else None),
                 wire_payload_bytes=wire_payload[r - 1],
                 wire_meta_bytes=wire_meta[r - 1],
-                wall_s=per_round_wall)
+                wall_s=per_round_wall,
+                compile_s=(c_seed + c_rounds + c_counts if r == 1
+                           else None))
         trace.stop_reason = "fixed_rounds"
     return KMeansParallelResult(
         centers=np.asarray(final),

@@ -16,11 +16,9 @@ real deployment pays. All shapes are static; removed points are masked.
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
-import functools
 import math
-from typing import Any, NamedTuple, Optional, Tuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,15 +26,15 @@ import numpy as np
 from jax import lax
 
 from repro.configs.soccer_paper import SoccerParams
-from repro.core.comm import VirtualCluster, WireTally, wire_tally
+from repro.core import programs
+from repro.core.comm import VirtualCluster
 from repro.obs import trace as obs_trace
-from repro.obs.trace import current_trace, span, timed_compile
+from repro.obs.trace import current_trace, span
 from repro.core.kmeans import kmeans
 from repro.core.minibatch import minibatch_kmeans
 from repro.core.sampling import draw_global_sample
 from repro.core.truncated_cost import removal_threshold, trim_top_mass
 from repro.kernels import ops
-from repro.kernels.tuning import autotune_mode
 
 
 @dataclasses.dataclass(frozen=True)
@@ -225,7 +223,6 @@ def _draw_sample(comm, const: SoccerConstants, key: jax.Array,
 
 def soccer_round(state: SoccerState, comm, const: SoccerConstants
                  ) -> SoccerState:
-    obs_trace.count("programs_traced")      # runs once per trace, not per call
     key, k_s1, k_s2, k_bb, k_strag1, k_strag2 = jax.random.split(
         state.key, 6)
     alive_eff = state.alive & state.machine_ok[:, None]
@@ -312,7 +309,6 @@ def soccer_finalize(state: SoccerState, comm, const: SoccerConstants
     the HT weights before the final fit — the blackbox never spends
     centers chasing the z farthest cost units.
     """
-    obs_trace.count("programs_traced")      # runs once per trace, not per call
     key, k_bb = jax.random.split(state.key)
     alive_eff = state.alive & state.machine_ok[:, None]
     n_local = jnp.sum(alive_eff, axis=1).astype(jnp.int32)
@@ -411,121 +407,28 @@ def stopping_rule(remaining: float, capacity: float, prev: float) -> bool:
     return remaining > capacity and remaining < prev
 
 
-# ------------------------------------------------------ the program cache
-
-# SOCCER's init, round and finalize programs, built once per process and
-# key (``_program_key``): a fit whose key is here traces nothing. LRU, so
-# a sweep over many shapes holds at most this many triples.
-_PROGRAM_CACHE_SIZE = 8
-_PROGRAMS: "collections.OrderedDict[tuple, Tuple[_Program, ...]]" = (
-    collections.OrderedDict())
-
-
-@dataclasses.dataclass
-class _Program:
-    """One compiled SOCCER program and what its last trace recorded."""
-    fn: Any                              # backend.compile(...)
-    tally: Optional[WireTally] = None    # wire bytes; None until traced
-    aot: Any = None                      # timed_compile's executable,
-                                         # made by the first traced fit
-
-
-def clear_program_cache() -> None:
-    """Forget every cached program: the next fit builds both anew."""
-    _PROGRAMS.clear()
-
-
-def _program_key(backend, comm, const: SoccerConstants,
-                 inputs: tuple) -> tuple:
-    """Everything that decides what tracing the three programs produces:
-    the placement, the constants, the aval and sharding of every placed
-    input ``(x, w, alive, key)`` (None where not given), which fix those
-    of the state, and the trace-time kernel settings."""
-    leaves = tuple(None if a is None else
-                   (jnp.shape(a), jnp.result_type(a),
-                    getattr(a, "weak_type", False),
-                    getattr(a, "sharding", None))
-                   for a in inputs)
-    return (backend, comm, const, leaves, ops._backend(None),
-            autotune_mode())
-
-
-def _programs(backend, comm, const: SoccerConstants, inputs: tuple
-              ) -> Tuple[_Program, _Program, _Program]:
-    """The (init, round, finalize) programs for this fit's placed inputs
-    ``(x, w, alive, key)``: those an earlier fit with the same key built,
-    else new ones, cached unless part of the key cannot be hashed (a
-    caller-supplied comm, say). Init runs ``_new_leaves`` on ``alive``."""
-    key = _program_key(backend, comm, const, inputs)
-    try:
-        progs = _PROGRAMS.get(key)
-    except TypeError:
-        key = progs = None
-    if progs is not None:
-        _PROGRAMS.move_to_end(key)
-        return progs
-    x, w, alive, _ = inputs
-    _, p, d = x.shape
-    init = _Program(backend.compile(
-        functools.partial(_new_leaves, comm=comm, const=const, p=p, d=d,
-                          make_w=w is None),
-        (None if alive is None else "machine",),
-        STATE_MARKS._replace(x=None, key=None,
-                             w="machine" if w is None else None,
-                             alive="machine" if alive is None else None)))
-    progs = (init,) + tuple(_Program(backend.compile(
-        functools.partial(body, comm=comm, const=const),
-        (STATE_MARKS,), STATE_MARKS))
-        for body in (soccer_round, soccer_finalize))
-    if key is not None:
-        _PROGRAMS[key] = progs
-        if len(_PROGRAMS) > _PROGRAM_CACHE_SIZE:
-            _PROGRAMS.popitem(last=False)
-    return progs
-
-
 def _place(backend, comm, const: SoccerConstants, given: tuple
-           ) -> Tuple[Tuple[_Program, _Program, _Program], SoccerState]:
+           ) -> SoccerState:
     """Put the caller's ``(x, w, alive, key)`` on the devices, each
     machine's rows on its own, and build the rest of the state there with
-    the init program; returns the fit's programs and its first state.
-    Only the state holds the placed arrays once this returns, so a round's
-    copy of ``x`` replaces it rather than adding to it."""
+    the init program; returns the first state. Only the state holds the
+    placed arrays once this returns, so a round's copy of ``x`` replaces
+    it rather than adding to it."""
     obs_trace.count_put(*given[:3])
     placed = backend.put(given, tuple(
         None if a is None else mk for a, mk in zip(given, INPUT_MARKS)))
     obs_trace.count_placement(given, placed)
-    progs = _programs(backend, comm, const, placed)
     x, w, alive, key = placed
-    return progs, _with_given(progs[0].fn(alive), x, key, w, alive)
-
-
-def _traced(prog: _Program, fn, *args):
-    """``fn(*args)`` under a fresh wire tally. If it traced ``prog`` (the
-    tally recorded something, or ``prog`` was never traced), the tally
-    replaces the stored one: a retrace never adds to it."""
-    fresh = WireTally()
-    with wire_tally(fresh):
-        out = fn(*args)
-    if prog.tally is None or fresh != WireTally():
-        prog.tally = fresh
-    return out
-
-
-def _compile_ahead(prog: _Program, state: SoccerState,
-                   name: str) -> Optional[float]:
-    """A traced fit's AOT executable for ``prog``: compiled in a
-    ``soccer.compile`` span whose interval is returned, or 0.0 when an
-    earlier traced fit compiled it. None where the backend cannot lower
-    ahead of time (the first call then compiles inline)."""
-    if prog.aot is not None:
-        return 0.0
-    with span("soccer.compile", program=name) as sp:
-        aot, compile_s = _traced(prog, timed_compile, prog.fn, state)
-    if compile_s is None:
-        return None
-    prog.aot = aot
-    return sp.t1 - sp.t0
+    _, p, d = x.shape
+    init, _ = programs.build(
+        "soccer", "init", _new_leaves, backend,
+        (None if alive is None else "machine",),
+        STATE_MARKS._replace(x=None, key=None,
+                             w="machine" if w is None else None,
+                             alive="machine" if alive is None else None),
+        (alive,), static=dict(comm=comm, const=const, p=p, d=d,
+                              make_w=w is None))
+    return _with_given(init(alive), x, key, w, alive)
 
 
 def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
@@ -542,7 +445,12 @@ def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
     the stopping mechanism, no-progress guard, and round accounting below
     are shared by every deployment. ``on_round(round_idx, state)`` is an
     optional host callback after each round (checkpointing, failure
-    injection); if it returns a state, the loop continues from it.
+    injection); if it returns a state, the loop continues from it, placed
+    as the round program expects.
+
+    The init, round and finalize programs come from ``core.programs``:
+    built once per process and key, each the same executable whether the
+    fit is traced or not.
     """
     from repro.api.backends import check_uplink_wire, resolve_backend
     m, p, _ = x_parts.shape
@@ -556,60 +464,54 @@ def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
             getattr(backend, "uplink_wire", "auto"), ud))
     key = jax.random.PRNGKey(params.seed) if key is None else key
     trace = current_trace()
-    obs_trace.count("programs_traced", 0)   # reported on a cache hit too
     with span("soccer.place"):
-        (_, step, fin), state = _place(backend, comm, const,
-                                       (x_parts, w, alive, key))
+        state = _place(backend, comm, const, (x_parts, w, alive, key))
         if trace is not None:   # the copy lands here, not in round 1
             jax.block_until_ready(state)
-    obs_trace.count("program_cache_hits",
-                    sum(p.tally is not None for p in (step, fin)))
+
+    # the bodies are read from this module's globals here, at build time
+    step, compile_round = programs.build(
+        "soccer", "round", soccer_round, backend, (STATE_MARKS,),
+        STATE_MARKS, (state,), static=dict(comm=comm, const=const))
+    fin, compile_fin = programs.build(
+        "soccer", "finalize", soccer_finalize, backend, (STATE_MARKS,),
+        STATE_MARKS, (state,), static=dict(comm=comm, const=const))
 
     # The progress half of stopping_rule doubles as the no-progress
     # guard: if the threshold cannot remove anything (e.g. the truncation
     # mass exceeds N — coordinator far too small for this n), further
     # rounds are pure overhead; finalize on a subsample instead of
-    # spinning to max_rounds.
+    # spinning to max_rounds. Each record's wall and compile time is its
+    # span's own interval.
     rounds = 0
     prev_n = math.inf
     round_walls = []
-    compile_round = compile_fin = fin_wall = None
-    run_step, run_fin = step.fn, fin.fn
+    fin_wall = None
     if trace is not None:
         trace.meta.setdefault("eta", const.eta)
         trace.meta.setdefault("k", const.k)
         trace.meta.setdefault("max_rounds", const.max_rounds)
-        # AOT-compile both programs up front (once per process and key)
-        # so each round's wall_s is pure execution and compile_s is split
-        # out; on backends without a working .lower the compile stays
-        # inline (absorbed into round 1's wall, exactly the untraced
-        # behavior). Each record's wall and compile time is its span's
-        # own interval.
-        compile_round = _compile_ahead(step, state, "round")
-        compile_fin = _compile_ahead(fin, state, "finalize")
-        run_step, run_fin = (p.fn if p.aot is None else p.aot
-                             for p in (step, fin))
     while rounds < const.max_rounds:
         n_live = int(obs_trace.to_host(state.n_remaining))
         if not stopping_rule(n_live, const.eta, prev_n):
             break
         prev_n = n_live
-        if trace is None:
-            state = _traced(step, run_step, state)
-        else:
-            with span("soccer.round", round=rounds + 1) as sp:
-                state = _traced(step, run_step, state)
+        with span("soccer.round", round=rounds + 1) as sp:
+            state = step(state)
+            if trace is not None:
                 jax.block_until_ready(state.n_remaining)
+        if trace is not None:
             round_walls.append(sp.t1 - sp.t0)
         rounds += 1
         if on_round is not None:
-            state = on_round(rounds, state) or state
-    if trace is None:
-        state = _traced(fin, run_fin, state)
-    else:
-        with span("soccer.finalize") as sp:
-            state = _traced(fin, run_fin, state)
+            new = on_round(rounds, state)
+            if new is not None and new is not state:
+                state = backend.put(new, STATE_MARKS)
+    with span("soccer.finalize") as sp:
+        state = fin(state)
+        if trace is not None:
             jax.block_until_ready(state.centers)
+    if trace is not None:
         fin_wall = sp.t1 - sp.t0
 
     with span("soccer.readback"):
@@ -622,14 +524,12 @@ def run_soccer(x_parts: jax.Array, params: SoccerParams, *,
             n_rem = int(obs_trace.to_host(state.n_remaining))
     # achieved wire bytes: static per-trace payload + per-row widths of
     # the ragged channels x the realized row counts the state tracked
-    # (the programs' tallies from their last trace, this fit's or not)
-    t_round, t_fin = step.tally or WireTally(), fin.tally
     wire_payload = np.concatenate(
-        [t_round.bytes_at(up[:rounds]),
-         t_fin.bytes_at(up[rounds:rounds + 1])])
+        [step.tally.bytes_at(up[:rounds]),
+         fin.tally.bytes_at(up[rounds:rounds + 1])])
     wire_meta = np.concatenate(
-        [t_round.meta_bytes_at(up[:rounds]),
-         t_fin.meta_bytes_at(up[rounds:rounds + 1])])
+        [step.tally.meta_bytes_at(up[:rounds]),
+         fin.tally.meta_bytes_at(up[rounds:rounds + 1])])
     if trace is not None:
         _emit_soccer_records(trace, const, rounds, prev_n, n_rem, up,
                              n_hist, v_hist, a_hist, wire_payload,

@@ -23,10 +23,37 @@ import numpy as np
 
 from repro.api.registry import register_algorithm
 from repro.api.result import ClusterResult, uplink_bytes
+from repro.core import programs
 from repro.core.kmeans import kmeans
 from repro.core.minibatch import minibatch_kmeans
 from repro.core.sampling import gather_weighted
 from repro.coresets.sensitivity import build_coreset, default_coreset_size
+from repro.obs.trace import clock, current_trace
+
+
+def _one_round(kk, xp, wp, *, comm, k: int, t: int, kb: int, ud: str,
+               wire: str, blackbox: str, minibatch_size: int,
+               lloyd_iters: int):
+    """Every machine's coreset, gathered once; the black box on their
+    union."""
+    m = comm.m
+    ids = comm.machine_ids()
+    keys = jax.vmap(jax.random.fold_in, (None, 0))(kk, ids)
+    cpts, cw = jax.vmap(build_coreset, (0, 0, 0, None, None))(
+        keys, xp, wp, t, kb)
+    g_pts, g_w = gather_weighted(comm, cpts, cw, ud, wire=wire)
+    k_bb = jax.random.fold_in(kk, m + 1)      # coordinator's key
+    if blackbox == "minibatch":
+        centers, cost = minibatch_kmeans(k_bb, g_pts, g_w, k,
+                                         batch=minibatch_size)
+    else:
+        centers, cost = kmeans(k_bb, g_pts, g_w, k, lloyd_iters)
+    # same accounting as the SOCCER coreset uplink: every machine
+    # with any coreset mass ships its full fixed-width t-row block
+    # (weight-0 padding rows ride along)
+    machine_up = jnp.any(g_w.reshape(m, t) > 0, axis=1)
+    realized = jnp.sum(machine_up.astype(jnp.int32)) * t
+    return centers, cost, realized
 
 
 @register_algorithm("coreset_kmeans")
@@ -70,42 +97,20 @@ def fit_coreset_kmeans(x_parts, k: int, *, backend, key=None, w=None,
     w_dev = backend.put(jnp.asarray(w_np), "machine")
     key = jax.random.PRNGKey(seed) if key is None else key
 
-    def one_round(kk, xp, wp):
-        ids = comm.machine_ids()
-        keys = jax.vmap(jax.random.fold_in, (None, 0))(kk, ids)
-        cpts, cw = jax.vmap(build_coreset, (0, 0, 0, None, None))(
-            keys, xp, wp, t, kb)
-        g_pts, g_w = gather_weighted(comm, cpts, cw, ud, wire=wire)
-        k_bb = jax.random.fold_in(kk, m + 1)      # coordinator's key
-        if blackbox == "minibatch":
-            centers, cost = minibatch_kmeans(k_bb, g_pts, g_w, k,
-                                             batch=minibatch_size)
-        else:
-            centers, cost = kmeans(k_bb, g_pts, g_w, k, lloyd_iters)
-        # same accounting as the SOCCER coreset uplink: every machine
-        # with any coreset mass ships its full fixed-width t-row block
-        # (weight-0 padding rows ride along)
-        machine_up = jnp.any(g_w.reshape(m, t) > 0, axis=1)
-        realized = jnp.sum(machine_up.astype(jnp.int32)) * t
-        return centers, cost, realized
-
-    from repro.core.comm import WireTally, wire_tally
-    from repro.obs.trace import clock, current_trace, timed_compile
-    fn = backend.compile(one_round, ("rep", "machine", "machine"),
-                         ("rep", "rep", "rep"))
-    tally = WireTally()
+    fn, compile_s = programs.build(
+        "coreset_kmeans", "one_round", _one_round, backend,
+        ("rep", "machine", "machine"), ("rep", "rep", "rep"), (key, x, w_dev),
+        static=dict(comm=comm, k=k, t=t, kb=kb, ud=ud, wire=wire,
+                    blackbox=blackbox, minibatch_size=minibatch_size,
+                    lloyd_iters=lloyd_iters))
     trace = current_trace()
-    wall_s = compile_s = None
-    if trace is None:
-        with wire_tally(tally):
-            centers, cost, realized = fn(key, x, w_dev)
-    else:
-        with wire_tally(tally):
-            fn, compile_s = timed_compile(fn, key, x, w_dev)
-            t0 = clock()
-            centers, cost, realized = fn(key, x, w_dev)
-            jax.block_until_ready(centers)
-            wall_s = clock() - t0
+    t0 = clock() if trace is not None else 0.0
+    centers, cost, realized = fn(key, x, w_dev)
+    wall_s = None
+    if trace is not None:
+        jax.block_until_ready(centers)
+        wall_s = clock() - t0
+    tally = fn.tally
     up = np.asarray([int(realized)], np.int64)
     if trace is not None:
         trace.emit_round(

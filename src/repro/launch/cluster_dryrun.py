@@ -17,7 +17,6 @@ for the sharded-coordinator optimization.
   PYTHONPATH=src python -m repro.launch.cluster_dryrun [--multipod]
 """
 import argparse
-import dataclasses
 import functools
 import json
 import pathlib
@@ -25,11 +24,11 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from repro.api.backends import MeshBackend, mesh_comm
 from repro.configs.soccer_paper import SoccerParams
-from repro.core.distributed import _state_specs, make_mesh_step, mesh_cluster
-from repro.core.soccer import derive_constants, init_state
+from repro.core.soccer import (STATE_MARKS, derive_constants, init_state,
+                               soccer_round)
 from repro.launch.mesh import make_production_mesh
 from repro.roofline.analysis import Roofline
 from repro.roofline.hlo_stats import analyze_hlo
@@ -51,7 +50,7 @@ def run(mode: str, multi_pod: bool, n: int = 10_240_000, d: int = 15,
         k: int = 100, tag: str = "baseline") -> dict:
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh.devices.size
-    comm = mesh_cluster(mesh)
+    comm = mesh_comm(mesh)
     m = comm.m
     p_local = n // m
     params = SoccerParams(k=k, epsilon=0.1,
@@ -64,7 +63,9 @@ def run(mode: str, multi_pod: bool, n: int = 10_240_000, d: int = 15,
         lambda: init_state(
             jnp.zeros((m, p_local, d), jnp.float32), const,
             jax.random.PRNGKey(0)))
-    step = make_mesh_step(mesh, const)
+    step = MeshBackend(mesh).compile(
+        functools.partial(soccer_round, comm=comm, const=const),
+        (STATE_MARKS,), STATE_MARKS)
     t0 = time.time()
     lowered = step.lower(state_struct)
     compiled = lowered.compile()

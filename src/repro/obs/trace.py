@@ -23,9 +23,9 @@ Design constraints (the tentpole contract):
   between host and device (``h2d_bytes``/``d2h_bytes``, through
   ``to_host``/``count_put``), the most bytes any one device receives
   while its input is placed (``place_max_device_bytes``, through
-  ``count_placement``), the programs it traces
-  (``programs_traced``) and those it finds already built
-  (``program_cache_hits``).
+  ``count_placement``); ``core.programs`` counts, for every driver, the
+  programs a fit builds (``programs_traced``) and those it finds already
+  built (``program_cache_hits``).
 
 The per-round record schema is pinned (``ROUND_SCHEMA``): field names
 and value types are part of the exported JSONL contract and covered by a
@@ -371,30 +371,3 @@ def emit_round(**fields) -> None:
     if not _STACK:
         return
     _STACK[-1].emit_round(**fields)
-
-
-# ------------------------------------------------------- timing utilities
-
-
-def timed_compile(fn, *args):
-    """AOT-lower+compile a jitted callable for concrete ``args``, timed.
-
-    Returns ``(callable, compile_s)``. On success the callable is the
-    compiled executable — later calls pay zero trace/compile — and
-    ``compile_s`` is the measured trace+compile wall. A callable without
-    ``.lower`` (a plain-function stub) falls back to ``(fn, None)``: the
-    first call will compile inline and its round wall will absorb the
-    cost, exactly the untraced behavior. A compile error is raised here,
-    where it happens.
-
-    NOTE for callers recording wire bytes: jax traces ``fn`` *here*, so
-    the call must happen inside the same ``wire_tally`` context the
-    first execution would have used.
-    """
-    t0 = clock()
-    try:
-        lower = fn.lower
-    except AttributeError:
-        return fn, None
-    compiled = lower(*args).compile()
-    return compiled, clock() - t0

@@ -50,11 +50,13 @@ import numpy as np
 
 from repro.api.registry import register_algorithm
 from repro.api.result import ClusterResult, uplink_bytes
+from repro.core import programs
 from repro.core.kmeans import kmeans_plusplus
 from repro.core.sampling import gather_weighted
 from repro.core.truncated_cost import trim_top_mass
 from repro.coresets.sensitivity import build_coreset, default_coreset_size
 from repro.kernels import ops
+from repro.obs.trace import clock, current_trace
 
 
 def _machine_summary(key, xp, wp, t, t_out, kb):
@@ -85,6 +87,76 @@ def _machine_summary(key, xp, wp, t, t_out, kb):
     cpts, cw = build_coreset(k_cs, xp, wp_rest, t, kb)
     return (jnp.concatenate([cpts, cand_pts], axis=0),
             jnp.concatenate([cw, cand_w.astype(jnp.float32)], axis=0))
+
+
+def _one_round(kk, xp, wp, *, comm, k: int, t: int, t_out: int, kb: int,
+               ud: str, wire: str, outlier_frac: float, lloyd_iters: int):
+    """Summaries gathered once, trimmed Lloyd at the coordinator, and the
+    (k, z) objective of its centers on the full data."""
+    m, rows = comm.m, t + t_out
+    # candidate rows are seed-dead at the coordinator (per-machine
+    # layout [t coreset | t_out candidates], replicated after gather)
+    seed_mask = jnp.tile(jnp.concatenate(
+        [jnp.ones((t,), jnp.float32), jnp.zeros((t_out,), jnp.float32)]), m)
+    ids = comm.machine_ids()
+    keys = jax.vmap(jax.random.fold_in, (None, 0))(kk, ids)
+    pts, wts = jax.vmap(_machine_summary, (0, 0, 0, None, None, None))(
+        keys, xp, wp, t, t_out, kb)
+    g_pts, g_w = gather_weighted(comm, pts, wts, ud, wire=wire)
+
+    n_mass = comm.psum(jnp.sum(wp, axis=-1))  # population weight mass
+    z_mass = jnp.float32(outlier_frac) * n_mass
+    k_seed = jax.random.fold_in(kk, m + 1)    # coordinator's key
+    # best-of-R seeding: D² seeding alone merges light Zipf
+    # components often enough to dominate the error budget, so draw
+    # R independent seedings and keep the one with the lowest
+    # TRIMMED cost (outliers must not get a vote) — all
+    # coordinator-side, no extra communication
+    def seed_once(r):
+        c = kmeans_plusplus(jax.random.fold_in(k_seed, r), g_pts,
+                            g_w * seed_mask, k).astype(jnp.float32)
+        d2s, _ = ops.min_dist(g_pts, c)
+        return c, jnp.sum(trim_top_mass(d2s, g_w, z_mass) * d2s)
+
+    seeds = [seed_once(r) for r in range(4)]
+    best = jnp.argmin(jnp.stack([s[1] for s in seeds]))
+    c0 = jnp.stack([s[0] for s in seeds])[best]
+
+    def step(_, c):
+        d2, assign = ops.min_dist(g_pts, c)
+        w_t = trim_top_mass(d2, g_w, z_mass)
+        sums, counts = ops.lloyd_reduce(g_pts, w_t, assign, k)
+        return jnp.where(counts[:, None] > 0,
+                         sums / jnp.maximum(counts[:, None], 1e-30), c)
+
+    centers = jax.lax.fori_loop(0, lloyd_iters, step, c0)
+
+    # trim threshold realized on the gathered rows: the distance of
+    # the first KEPT row when the top-z mass is peeled off in
+    # descending order — re-applied to the full data below
+    d2g, _ = ops.min_dist(g_pts, centers)
+    order = jnp.argsort(-d2g)
+    cum = jnp.cumsum(g_w[order])
+    if outlier_frac > 0.0:
+        j = jnp.minimum(jnp.searchsorted(cum, z_mass),
+                        d2g.shape[0] - 1)
+        v = d2g[order][j]
+    else:
+        v = jnp.float32(np.finfo(np.float32).max)
+
+    # honest (k, z) objective: one fused sweep of the full data per
+    # machine, triples psum'd — nothing (n,)-sized materializes
+    kept, tmass, tcost = jax.vmap(
+        lambda xm, wm: ops.truncated_cost(xm, wm, centers, v))(xp, wp)
+    kept = comm.psum(kept)
+    tmass = comm.psum(tmass)
+    tcost = comm.psum(tcost)
+
+    # same accounting as coreset_kmeans: every machine with any
+    # uplink mass ships its full fixed-width rows-block
+    machine_up = jnp.any(g_w.reshape(m, rows) > 0, axis=1)
+    realized = jnp.sum(machine_up.astype(jnp.int32)) * rows
+    return centers, kept, tmass, tcost, v, realized
 
 
 @register_algorithm("kzmeans")
@@ -135,89 +207,20 @@ def fit_kzmeans(x_parts, k: int, *, backend, key=None, w=None, alive=None,
         w_np = np.where(np.asarray(alive), w_np, 0.0).astype(np.float32)
     w_dev = backend.put(jnp.asarray(w_np), "machine")
     key = jax.random.PRNGKey(seed) if key is None else key
-    # candidate rows are seed-dead at the coordinator (per-machine
-    # layout [t coreset | t_out candidates], replicated after gather)
-    seed_mask = jnp.tile(jnp.concatenate(
-        [jnp.ones((t,), jnp.float32), jnp.zeros((t_out,), jnp.float32)]), m)
-
-    def one_round(kk, xp, wp):
-        ids = comm.machine_ids()
-        keys = jax.vmap(jax.random.fold_in, (None, 0))(kk, ids)
-        pts, wts = jax.vmap(_machine_summary, (0, 0, 0, None, None, None))(
-            keys, xp, wp, t, t_out, kb)
-        g_pts, g_w = gather_weighted(comm, pts, wts, ud, wire=wire)
-
-        n_mass = comm.psum(jnp.sum(wp, axis=-1))  # population weight mass
-        z_mass = jnp.float32(outlier_frac) * n_mass
-        k_seed = jax.random.fold_in(kk, m + 1)    # coordinator's key
-        # best-of-R seeding: D² seeding alone merges light Zipf
-        # components often enough to dominate the error budget, so draw
-        # R independent seedings and keep the one with the lowest
-        # TRIMMED cost (outliers must not get a vote) — all
-        # coordinator-side, no extra communication
-        def seed_once(r):
-            c = kmeans_plusplus(jax.random.fold_in(k_seed, r), g_pts,
-                                g_w * seed_mask, k).astype(jnp.float32)
-            d2s, _ = ops.min_dist(g_pts, c)
-            return c, jnp.sum(trim_top_mass(d2s, g_w, z_mass) * d2s)
-
-        seeds = [seed_once(r) for r in range(4)]
-        best = jnp.argmin(jnp.stack([s[1] for s in seeds]))
-        c0 = jnp.stack([s[0] for s in seeds])[best]
-
-        def step(_, c):
-            d2, assign = ops.min_dist(g_pts, c)
-            w_t = trim_top_mass(d2, g_w, z_mass)
-            sums, counts = ops.lloyd_reduce(g_pts, w_t, assign, k)
-            return jnp.where(counts[:, None] > 0,
-                             sums / jnp.maximum(counts[:, None], 1e-30), c)
-
-        centers = jax.lax.fori_loop(0, lloyd_iters, step, c0)
-
-        # trim threshold realized on the gathered rows: the distance of
-        # the first KEPT row when the top-z mass is peeled off in
-        # descending order — re-applied to the full data below
-        d2g, _ = ops.min_dist(g_pts, centers)
-        order = jnp.argsort(-d2g)
-        cum = jnp.cumsum(g_w[order])
-        if outlier_frac > 0.0:
-            j = jnp.minimum(jnp.searchsorted(cum, z_mass),
-                            d2g.shape[0] - 1)
-            v = d2g[order][j]
-        else:
-            v = jnp.float32(np.finfo(np.float32).max)
-
-        # honest (k, z) objective: one fused sweep of the full data per
-        # machine, triples psum'd — nothing (n,)-sized materializes
-        kept, tmass, tcost = jax.vmap(
-            lambda xm, wm: ops.truncated_cost(xm, wm, centers, v))(xp, wp)
-        kept = comm.psum(kept)
-        tmass = comm.psum(tmass)
-        tcost = comm.psum(tcost)
-
-        # same accounting as coreset_kmeans: every machine with any
-        # uplink mass ships its full fixed-width rows-block
-        machine_up = jnp.any(g_w.reshape(m, rows) > 0, axis=1)
-        realized = jnp.sum(machine_up.astype(jnp.int32)) * rows
-        return centers, kept, tmass, tcost, v, realized
-
-    from repro.core.comm import WireTally, wire_tally
-    from repro.obs.trace import clock, current_trace, timed_compile
-    fn = backend.compile(one_round, ("rep", "machine", "machine"),
-                         ("rep",) * 6)
-    tally = WireTally()
+    fn, compile_s = programs.build(
+        "kzmeans", "one_round", _one_round, backend,
+        ("rep", "machine", "machine"), ("rep",) * 6, (key, x, w_dev),
+        static=dict(comm=comm, k=k, t=t, t_out=t_out, kb=kb, ud=ud,
+                    wire=wire, outlier_frac=outlier_frac,
+                    lloyd_iters=lloyd_iters))
     trace = current_trace()
-    wall_s = compile_s = None
-    if trace is None:
-        with wire_tally(tally):
-            centers, kept, tmass, tcost, v, realized = fn(key, x, w_dev)
-    else:
-        with wire_tally(tally):
-            fn, compile_s = timed_compile(fn, key, x, w_dev)
-            t0 = clock()
-            centers, kept, tmass, tcost, v, realized = fn(key, x, w_dev)
-            jax.block_until_ready(centers)
-            wall_s = clock() - t0
+    t0 = clock() if trace is not None else 0.0
+    centers, kept, tmass, tcost, v, realized = fn(key, x, w_dev)
+    wall_s = None
+    if trace is not None:
+        jax.block_until_ready(centers)
+        wall_s = clock() - t0
+    tally = fn.tally
     up = np.asarray([int(realized)], np.int64)
     if trace is not None:
         trace.emit_round(

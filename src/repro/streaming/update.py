@@ -30,13 +30,12 @@ stream):
   gather of the live tree rows; rounds happen only if the caller
   constrains the coordinator via ``recluster_params``).
 
-Backends: virtual/comm backends are supported; the mesh leg needs the
+Backends: the virtual backend is supported; the mesh leg needs the
 tree fold re-driven through ``Backend.compile`` and is left as the
 multi-host extension point (ROADMAP).
 """
 from __future__ import annotations
 
-import functools
 import math
 from typing import Optional
 
@@ -47,6 +46,7 @@ import numpy as np
 from repro.api.backends import MeshBackend, resolve_backend
 from repro.api.registry import get_algorithm
 from repro.api.result import ClusterResult, uplink_bytes
+from repro.core import programs
 from repro.core.kmeans import kmeans
 from repro.core.metrics import assignment_counts, distributed_cost
 from repro.core.sharded_kmeans import distributed_lloyd
@@ -62,19 +62,22 @@ from repro.streaming.tree import flatten_tree, fold_batch, stream_bucket
 DRIFT_EVENTS = REGISTRY.event_log("streaming.drift.events")
 
 
-@functools.lru_cache(maxsize=None)
-def _compiled_refine(backend, comm, iters: int):
-    """One compiled warm-start body per (backend, comm, iters) — cached
-    so repeated updates reuse the jit cache instead of retracing."""
+def _refine(pts, ws, centers, *, comm, iters: int):
+    """Warm-start Lloyd over the tree coreset, with the refined centers'
+    cost and the coreset's total weight."""
+    new = distributed_lloyd(comm, pts, ws, centers, iters)
+    cost = distributed_cost(comm, pts, ws, new)
+    total_w = comm.psum(jnp.sum(ws, axis=1))
+    return new, cost, total_w
 
-    def refine(pts, ws, centers):
-        new = distributed_lloyd(comm, pts, ws, centers, iters)
-        cost = distributed_cost(comm, pts, ws, new)
-        total_w = comm.psum(jnp.sum(ws, axis=1))
-        return new, cost, total_w
 
-    return backend.compile(refine, ("machine", "machine", "rep"),
-                           ("rep", "rep", "rep"))
+def _refined(backend, comm, iters: int, pts, ws, centers):
+    """``_refine`` run as the program built for these inputs."""
+    prog, _ = programs.build("stream", "refine", _refine, backend,
+                             ("machine", "machine", "rep"),
+                             ("rep", "rep", "rep"), (pts, ws, centers),
+                             static=dict(comm=comm, iters=iters))
+    return prog(pts, ws, centers)
 
 
 def _shard_stream_batch(x_new: np.ndarray, w_new: Optional[np.ndarray],
@@ -183,7 +186,7 @@ def fit_update(result: ClusterResult, x_new, *, backend=None,
     bk = resolve_backend(backend, state.m)
     if isinstance(bk, MeshBackend):
         raise NotImplementedError(
-            "fit_update currently runs on the virtual/comm backends; the "
+            "fit_update currently runs on the virtual backend; the "
             "mesh leg is the multi-host extension point (ROADMAP)")
     comm = bk.make_comm(state.m)
     d = state.centers.shape[1]
@@ -200,9 +203,9 @@ def fit_update(result: ClusterResult, x_new, *, backend=None,
     # --- 2. warm-start Lloyd over the flattened tree coreset
     pts, wts = flatten_tree(state.levels, state.occupied, state.m,
                             state.t, d)
-    refine = _compiled_refine(bk, comm, refine_iters)
-    centers, cost, total_w = refine(pts, wts,
-                                    jnp.asarray(state.centers, jnp.float32))
+    centers, cost, total_w = _refined(
+        bk, comm, refine_iters, pts, wts,
+        jnp.asarray(state.centers, jnp.float32))
     cost_per_w = float(cost) / max(float(total_w), 1e-30)
     up_rows = state.m * state.k * refine_iters
 
@@ -237,7 +240,8 @@ def fit_update(result: ClusterResult, x_new, *, backend=None,
         masses = assignment_counts(comm, pts, wts, union)
         state.key, k_cond = jax.random.split(state.key)
         cond, _ = kmeans(k_cond, union, masses, state.k, 10)
-        centers, cost, total_w = refine(pts, wts, cond)
+        centers, cost, total_w = _refined(bk, comm, refine_iters, pts, wts,
+                                          cond)
         cost_per_w = float(cost) / max(float(total_w), 1e-30)
         up_rows += int(rc.uplink_points_total)
         state.n_reclusters += 1

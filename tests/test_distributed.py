@@ -20,7 +20,6 @@ from repro.api import MeshBackend, fit, list_algorithms
 from repro.configs.soccer_paper import GaussianMixtureSpec, SoccerParams
 from repro.data.synthetic import gaussian_mixture, shard_points
 from repro.core.soccer import run_soccer
-from repro.core.distributed import run_soccer_mesh
 from repro.core.metrics import centralized_cost
 
 spec = GaussianMixtureSpec(n=8_000, dim=10, k=5, sigma=0.001, seed=3)
@@ -33,7 +32,7 @@ for sharded in (False, True):
     params = SoccerParams(k=5, epsilon=0.1, seed=3,
                           sharded_coordinator=sharded)
     rv = run_soccer(parts, params)
-    rm = run_soccer_mesh(parts, params, mesh)
+    rm = run_soccer(parts, params, backend=MeshBackend(mesh))
     out[f"virtual_cost_{sharded}"] = float(
         centralized_cost(xg, jnp.asarray(rv.centers)))
     out[f"mesh_cost_{sharded}"] = float(
@@ -46,7 +45,7 @@ for sharded in (False, True):
 # facade must be bit-identical to the legacy drivers on both backends
 params = SoccerParams(k=5, epsilon=0.1, seed=3)
 rv = run_soccer(parts, params)
-rm = run_soccer_mesh(parts, params, mesh)
+rm = run_soccer(parts, params, backend=MeshBackend(mesh))
 fv = fit(parts, 5, algo="soccer", backend="virtual", epsilon=0.1, seed=3)
 fm = fit(parts, 5, algo="soccer", backend=MeshBackend(mesh), epsilon=0.1,
          seed=3)

@@ -166,3 +166,48 @@ def test_place_counter_is_one_machines_share(out, case):
     """The most any device receives while the input is placed: one
     machine's rows on the mesh, all of them on the one virtual device."""
     assert out["place"][case] == PLACED[case]
+
+
+_FAILURE_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+import jax, numpy as np
+from repro.api import fit
+from repro.ft.failures import FailurePlan
+
+x = np.random.default_rng(1).normal(size=(4, 4096, 8)).astype(np.float32)
+def run(trace):
+    res = fit(x, 4, algo="soccer", backend="mesh", epsilon=0.2,
+              eta_override=400, key=jax.random.PRNGKey(5), seed=5,
+              failure_plan=FailurePlan(fail_at={1: (2,)}), trace=trace)
+    st = res.extra["state"]
+    return [np.asarray(a).tolist() for a in (
+        res.centers, res.n_hist, res.v_hist, res.wire_bytes,
+        st.machine_ok, st.alive)] + [res.rounds]
+plain, traced = run(None), run("rounds")
+print("RESULT " + json.dumps({"devices": jax.device_count(),
+                              "rounds": plain[-1],
+                              "machine_ok": plain[4],
+                              "same": plain == traced}))
+"""
+
+
+def test_mesh_failure_after_round_one_traced_and_untraced_agree():
+    """A machine killed after round 1 on the mesh: the hook's state is
+    placed for the next program, and a traced fit returns bit for bit
+    what an untraced one does."""
+    env = dict(os.environ)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    env["PYTHONPATH"] = str(root / "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run([sys.executable, "-c", _FAILURE_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = [ln for ln in proc.stdout.splitlines()
+            if ln.startswith("RESULT ")][-1]
+    out = json.loads(line[len("RESULT "):])
+    assert out["devices"] == M
+    assert out["rounds"] >= 1
+    assert out["machine_ok"] == [True, True, False, True]
+    assert out["same"]

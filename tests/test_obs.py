@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from repro.api import fit
-from repro.core import soccer
+from repro.core import programs
 from repro.obs import REGISTRY, MetricsRegistry
 from repro.obs.export import (chrome_trace_events, load_jsonl, write_jsonl)
 from repro.obs.report import format_diff, format_summary, main as report_main
@@ -31,9 +31,9 @@ def _data(seed=0, p=256, d=8):
 
 @pytest.fixture
 def fresh_programs():
-    """An empty SOCCER program cache, so the fit under test is the first
-    of its shapes in the process: it traces and compiles both programs."""
-    soccer.clear_program_cache()
+    """An empty program cache, so the fit under test is the first of its
+    shapes in the process: it traces and compiles all three programs."""
+    programs.clear_program_cache()
 
 
 def _soccer(x, trace=None, seed=0):
@@ -161,25 +161,34 @@ def _full_soccer(x):
 
 
 def test_traced_fit_spans_every_host_phase_in_order(fresh_programs):
+    """Spans are listed as they close: the init program is built while
+    the input is placed, the round and finalize programs after it."""
     res, t = _full_soccer(_data(p=2048))
     assert [s["name"] for s in t["spans"]] == (
-        ["fit.place", "soccer.place", "soccer.compile", "soccer.compile"]
+        ["fit.place", "soccer.compile", "soccer.place", "soccer.compile",
+         "soccer.compile"]
         + ["soccer.round"] * res.rounds
         + ["soccer.finalize", "soccer.readback"])
     assert [s["attrs"] for s in t["spans"] if s["name"] == "soccer.compile"
-            ] == [{"program": "round"}, {"program": "finalize"}]
+            ] == [{"program": "init"}, {"program": "round"},
+                  {"program": "finalize"}]
     assert [s["attrs"]["round"] for s in t["spans"]
             if s["name"] == "soccer.round"] == list(range(1, res.rounds + 1))
-    # inside the fit's wall, and not overlapping each other
-    for a, b in zip(t["spans"], t["spans"][1:]):
+    # the init build inside the placement; the rest inside the fit's
+    # wall, and not overlapping each other
+    init, place = t["spans"][1:3]
+    assert place["t0"] <= init["t0"] <= init["t1"] <= place["t1"]
+    phases = [t["spans"][0]] + t["spans"][2:]
+    for a, b in zip(phases, phases[1:]):
         assert a["t0"] <= a["t1"] <= b["t0"] <= b["t1"]
-    covered = sum(s["t1"] - s["t0"] for s in t["spans"])
+    covered = sum(s["t1"] - s["t0"] for s in phases)
     assert 0 < covered <= t["wall_s"]
 
 
 def test_span_durations_are_the_records_walls(fresh_programs):
     """One measurement, not two: each round, finalize and compile span's
-    interval is its record's wall_s / compile_s."""
+    interval is its record's wall_s / compile_s (the init program's build
+    belongs to the placement, which has no record)."""
     res, t = _full_soccer(_data(p=2048))
     dur = {}
     for s in t["spans"]:
@@ -188,7 +197,7 @@ def test_span_durations_are_the_records_walls(fresh_programs):
     assert [r["wall_s"] for r in recs[:-1]] == dur["soccer.round"]
     assert [recs[-1]["wall_s"]] == dur["soccer.finalize"]
     assert [recs[0]["compile_s"], recs[-1]["compile_s"]] == dur[
-        "soccer.compile"]
+        "soccer.compile"][1:]
 
 
 @pytest.mark.parametrize("on_device", [False, True])
@@ -207,7 +216,7 @@ def test_fit_counts_programs_and_host_device_bytes(on_device,
         state.uplink, state.n_hist, state.v_hist, state.alpha_hist,
         state.centers, state.centers_valid, state.n_remaining))
     assert t["counters"] == {
-        "programs_traced": 2,
+        "programs_traced": 3,
         "program_cache_hits": 0,
         "h2d_bytes": x.nbytes,
         "place_max_device_bytes": x.nbytes,   # one device takes all of x

@@ -183,9 +183,13 @@ class MeshBackend:
             # array from host-local data — each process contributes the
             # machine rows of ITS devices (MACHINE leaves arrive as the
             # process-local (m // process_count, ...) slab; REPLICATED
-            # leaves arrive whole on every process).
+            # leaves arrive whole on every process). A jax.Array leaf is
+            # a global value already, possibly not fully addressable, and
+            # is resharded on the devices without a host copy.
             def _place(leaf, mk):
                 sharding = NamedSharding(self.mesh, self._spec(mk))
+                if isinstance(leaf, jax.Array):
+                    return jax.device_put(leaf, sharding)
                 return jax.make_array_from_process_local_data(
                     sharding, np.asarray(leaf))
             return jax.tree.map(_place, tree, marks)

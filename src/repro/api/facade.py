@@ -4,11 +4,14 @@
     res = fit(x, k=25, algo="soccer", backend="auto", epsilon=0.1)
     res.centers, res.rounds, res.uplink_points, res.cost(x)
 
-``x`` is either flat ``(n, d)`` data (placed on ``m`` machines by
-``shard_policy`` — shuffled/contiguous/sorted/imbalanced, see
-``repro.data.sharding``) or pre-sharded ``(m, p, d)`` — the latter is
-passed through untouched, so facade runs are bit-identical to the legacy
-per-algorithm drivers for the same PRNG key.
+``x`` is either flat ``(n, d)`` data (read to the host and placed on
+``m`` machines by ``shard_policy`` — shuffled/contiguous/sorted/
+imbalanced, see ``repro.data.sharding``) or pre-sharded ``(m, p, d)`` —
+the latter is passed through untouched, so facade runs are bit-identical
+to the legacy per-algorithm drivers for the same PRNG key. A pre-sharded
+``jax.Array`` stays on its devices: the driver's placement moves it
+device to device, or not at all where it already lies as the backend
+places it.
 
 Run *conditions* are facade knobs too: ``uplink_dtype`` sets the
 machine->coordinator payload precision (quantized before the upload and
@@ -74,7 +77,8 @@ def fit(x, k: int, algo: str = "soccer", backend="auto", *,
     """Cluster ``x`` into ``k`` groups with any registered algorithm.
 
     Args:
-      x: ``(n, d)`` points or ``(m, p, d)`` machine-sharded points.
+      x: ``(n, d)`` points or ``(m, p, d)`` machine-sharded points; a
+        pre-sharded ``jax.Array`` is not read to the host.
       k: number of clusters.
       algo: registered algorithm name (``repro.api.list_algorithms()``).
       backend: "virtual" | "mesh" | "auto", a ``jax.sharding.Mesh``, or a
@@ -119,11 +123,11 @@ def fit(x, k: int, algo: str = "soccer", backend="auto", *,
         removal threshold, stopping-rule margin, uplink rows, achieved
         wire bytes, wall/compile split) land in
         ``result.extra["trace"]``, with per-fit counters (host<->device
-        bytes, programs traced); "full" — additionally records the
-        host phases as spans (input placement, program builds, each
-        round, finalize, readback), mirrored into
-        ``jax.profiler.TraceAnnotation`` so a captured profile shows
-        them on its host plane, plus events, for the
+        bytes, device-resident input kept, programs traced); "full" —
+        additionally records the host phases as spans (input
+        placement, program builds, each round, finalize, readback),
+        mirrored into ``jax.profiler.TraceAnnotation`` so a captured
+        profile shows them on its host plane, plus events, for the
         Chrome-trace/Perfetto export (``repro.obs.export``,
         ``python -m repro.obs.report``). Each traced fit also appends
         its totals to the ``api.fit.traces`` event log of
@@ -196,7 +200,13 @@ def fit(x, k: int, algo: str = "soccer", backend="auto", *,
     with (obs_trace.run_trace(rt) if rt is not None
           else contextlib.nullcontext()):
         with obs_trace.span("fit.place"):
-            x = obs_trace.to_host(x)
+            # a pre-sharded device array goes to the driver as it is: its
+            # placement is then device to device, or nothing to do
+            on_device = len(shape) == 3 and isinstance(x, jax.Array)
+            obs_trace.count("input_on_device_bytes",
+                            x.nbytes if on_device else 0)
+            if not on_device:
+                x = obs_trace.to_host(x)
             parts, w_parts, alive_parts = _as_parts(x, w, m, seed, policy)
             if init_dead:
                 w_parts, alive_parts = _mask_failed_machines(

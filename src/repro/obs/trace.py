@@ -23,9 +23,11 @@ Design constraints (the tentpole contract):
   between host and device (``h2d_bytes``/``d2h_bytes``, through
   ``to_host``/``count_put``), the most bytes any one device receives
   while its input is placed (``place_max_device_bytes``, through
-  ``count_placement``); ``core.programs`` counts, for every driver, the
-  programs a fit builds (``programs_traced``) and those it finds already
-  built (``program_cache_hits``).
+  ``count_placement``); ``api.fit`` counts the bytes of a pre-sharded
+  device input it hands on without a host trip (``input_on_device_bytes``,
+  0 where the input goes through the host); ``core.programs`` counts, for
+  every driver, the programs a fit builds (``programs_traced``) and those
+  it finds already built (``program_cache_hits``).
 
 The per-round record schema is pinned (``ROUND_SCHEMA``): field names
 and value types are part of the exported JSONL contract and covered by a

@@ -194,3 +194,47 @@ def test_cost_helper_matches_centralized(data):
     # sharded input with weights gives the same total
     w = jnp.ones(parts.shape[:2])
     assert res.cost(parts, w) == pytest.approx(direct, rel=1e-5)
+
+
+@pytest.mark.parametrize("algo", sorted(TINY))
+def test_device_input_fits_as_its_host_copy(data, algo):
+    """A pre-sharded device array goes to the driver without a host trip
+    and clusters bit for bit as the same points given as NumPy."""
+    _, parts, _ = data
+    on_device = fit(parts, K, algo=algo, backend="virtual", seed=5,
+                    trace="rounds", **TINY.get(algo, {}))
+    on_host = fit(np.asarray(parts), K, algo=algo, backend="virtual",
+                  seed=5, trace="rounds", **TINY.get(algo, {}))
+    assert np.array_equal(on_device.centers, on_host.centers)
+    assert on_device.rounds == on_host.rounds
+    assert np.array_equal(on_device.uplink_points, on_host.uplink_points)
+    kept = [r.extra["trace"]["counters"]["input_on_device_bytes"]
+            for r in (on_device, on_host)]
+    assert kept == [parts.nbytes, 0]
+
+
+def test_multi_host_put_reshards_device_leaves(monkeypatch):
+    """Across processes a jax.Array leaf is a global value: it is put on
+    the mesh by device_put, never read to the host; a host leaf is the
+    process's own data and still goes through
+    make_array_from_process_local_data."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.api import MeshBackend
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("machines",))
+    bk = MeshBackend(mesh)
+    local, put = [], []
+    from_local = jax.make_array_from_process_local_data
+    device_put = jax.device_put
+    monkeypatch.setattr(jax, "process_count", lambda: 2)
+    monkeypatch.setattr(jax, "make_array_from_process_local_data",
+                        lambda s, a: local.append(a) or from_local(s, a))
+    monkeypatch.setattr(jax, "device_put",
+                        lambda a, s: put.append(a) or device_put(a, s))
+    xd = jnp.arange(12, dtype=jnp.float32).reshape(1, 4, 3)
+    key = np.asarray([0, 7], np.uint32)
+    x_out, key_out = bk.put((xd, key), ("machine", "rep"))
+    assert len(put) == 1 and put[0] is xd
+    assert len(local) == 1 and local[0] is key
+    assert x_out.sharding == NamedSharding(mesh, PartitionSpec("machines"))
+    assert key_out.sharding == NamedSharding(mesh, PartitionSpec())
+    assert np.array_equal(x_out, xd) and np.array_equal(key_out, key)

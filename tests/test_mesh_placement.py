@@ -5,8 +5,10 @@ builds the rest of the state there, so no device ever holds another
 machine's points. One subprocess with four CPU devices runs the paper's
 mixture at 64,000 points (d = 15, k = 25) and reports what these tests
 check: the fit against a plain float32 difference-form reference, the
-mesh fit against the virtual one on the same key, where each machine's
-leaves live, and the ``place_max_device_bytes`` counter.
+mesh fit against the virtual one on the same key, the mesh fit of the
+points already sharded on the devices against the one of their NumPy
+copy, where each machine's leaves live, and the
+``place_max_device_bytes`` counter.
 """
 import json
 import os
@@ -23,6 +25,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import json
 import jax, numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from repro.api import fit
 from repro.core import soccer
 from repro.ft.failures import FailurePlan
@@ -35,6 +38,10 @@ labels = rng.choice(K, size=(M, P), p=zipf / zipf.sum())
 x = (means[labels] + np.float32(0.001) * rng.normal(size=(M, P, D))
      ).astype(np.float32)
 w = rng.uniform(0.5, 2.0, size=(M, P)).astype(np.float32)
+# the same points already on the devices, one machine on each
+xd = jax.device_put(x, NamedSharding(
+    Mesh(np.asarray(jax.devices()[:M]), ("machines",)),
+    PartitionSpec("machines")))
 
 placed = []
 with_given = soccer._with_given
@@ -43,8 +50,8 @@ def spy(*args):
     return placed[-1]
 soccer._with_given = spy
 
-def run(backend, **kw):
-    res = fit(x, K, algo="soccer", backend=backend, epsilon=0.05, delta=0.1,
+def run(backend, data=x, **kw):
+    res = fit(data, K, algo="soccer", backend=backend, epsilon=0.05, delta=0.1,
               key=jax.random.PRNGKey(7), seed=7, trace="rounds", **kw)
     return res, placed[-1]
 
@@ -67,6 +74,12 @@ mesh, mesh_state = run("mesh")
 virtual, _ = run("virtual")
 out["bit_equal"] = all(np.array_equal(a, b) for a, b in
                        zip(outcome(mesh), outcome(virtual)))
+on_device, _ = run("mesh", data=xd)
+out["device_bit_equal"] = all(np.array_equal(a, b) for a, b in
+                              zip(outcome(on_device), outcome(mesh)))
+out["device_counters"] = {
+    name: on_device.extra["trace"]["counters"].get(name)
+    for name in ("h2d_bytes", "input_on_device_bytes")}
 
 st = mesh.extra["state"]
 flat = x.reshape(-1, D)
@@ -104,6 +117,7 @@ out["place"] = {
     "mesh": placed_bytes("mesh"),
     "mesh_w": placed_bytes("mesh", w=w),
     "mesh_plan": placed_bytes("mesh", failure_plan=plan),
+    "mesh_device": placed_bytes("mesh", data=xd),
     "virtual": placed_bytes("virtual"),
     "virtual_plan": placed_bytes("virtual", failure_plan=plan),
 }
@@ -141,6 +155,14 @@ def test_mesh_fit_is_bit_equal_to_virtual(out):
     assert out["bit_equal"]
 
 
+def test_mesh_fit_of_sharded_device_input_is_bit_equal(out):
+    """Points already sharded one machine per device are fitted where they
+    lie, with no host copy, to the same result as their NumPy copy."""
+    assert out["device_bit_equal"]
+    assert out["device_counters"] == {"h2d_bytes": None,
+                                      "input_on_device_bytes": M * P * D * 4}
+
+
 @pytest.mark.parametrize("leaf", ["x", "w", "alive", "machine_ok"])
 def test_each_machine_lives_on_its_own_device(out, leaf):
     """(device, first machine, machines held) of every shard."""
@@ -150,12 +172,14 @@ def test_each_machine_lives_on_its_own_device(out, leaf):
 
 # Bytes of one machine's rows: x is (M, P, D) float32, w float32, alive
 # bool. The mesh's key (two uint32) is replicated from device 0, so the
-# other devices receive its 8 bytes besides their rows.
+# other devices receive its 8 bytes besides their rows; points already
+# sharded on the devices move not at all.
 X, W, ALIVE, KEY = P * D * 4, P * 4, P, 8
 PLACED = {
     "mesh": X + KEY,
     "mesh_w": X + W + KEY,
     "mesh_plan": X + W + ALIVE + KEY,
+    "mesh_device": KEY,
     "virtual": M * X,
     "virtual_plan": M * (X + W + ALIVE),
 }
@@ -173,6 +197,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import json
 import jax, numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from repro.api import fit
 from repro.ft.failures import FailurePlan
 

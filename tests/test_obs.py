@@ -203,6 +203,8 @@ def test_span_durations_are_the_records_walls(fresh_programs):
 @pytest.mark.parametrize("on_device", [False, True])
 def test_fit_counts_programs_and_host_device_bytes(on_device,
                                                  fresh_programs):
+    """A host input is put on the device once; a device-resident one is
+    handed on as it is: no host copy either way, nothing placed."""
     x = _data(p=2048)
     xin = jax.numpy.asarray(x) if on_device else x
     res = fit(xin, K, algo="soccer", backend="virtual", epsilon=0.2,
@@ -215,16 +217,41 @@ def test_fit_counts_programs_and_host_device_bytes(on_device,
     readback = sum(a.nbytes for a in (
         state.uplink, state.n_hist, state.v_hist, state.alpha_hist,
         state.centers, state.centers_valid, state.n_remaining))
+    host = {} if on_device else {"h2d_bytes": x.nbytes}
     assert t["counters"] == {
         "programs_traced": 3,
         "program_cache_hits": 0,
-        "h2d_bytes": x.nbytes,
-        "place_max_device_bytes": x.nbytes,   # one device takes all of x
-        "d2h_bytes": (x.nbytes if on_device else 0) + readback
-                     + checks * state.n_remaining.nbytes}
+        "input_on_device_bytes": x.nbytes if on_device else 0,
+        # one device takes all of a host x; a device x is already there
+        "place_max_device_bytes": 0 if on_device else x.nbytes,
+        "d2h_bytes": readback + checks * state.n_remaining.nbytes,
+        **host}
     line = REGISTRY.read("api.fit.traces")["api.fit.traces"]["events"][-1]
     assert line["counters"] == t["counters"]
     assert line["wall_s"] == t["wall_s"] and line["span_s"] == {}
+
+
+def test_flat_device_input_takes_the_host_shard_path(monkeypatch,
+                                                     fresh_programs):
+    """Flat (n, d) input is placed on machines by make_shards on the host
+    whether it comes as NumPy or on a device: a device one is read back
+    first, and both then cluster and count alike."""
+    from repro.data import sharding
+    seen = []
+    make_shards = sharding.make_shards
+    monkeypatch.setattr(sharding, "make_shards", lambda x, *a, **kw: (
+        seen.append(type(x)) or make_shards(x, *a, **kw)))
+    x = _data(p=512).reshape(M * 512, -1)
+    runs = [fit(xin, K, algo="soccer", backend="virtual", m=M,
+                epsilon=0.2, seed=0, trace="rounds")
+            for xin in (jax.numpy.asarray(x), x)]
+    assert seen == [np.ndarray, np.ndarray]
+    dev, host = (r.extra["trace"]["counters"] for r in runs)
+    assert dev["input_on_device_bytes"] == host["input_on_device_bytes"] == 0
+    assert dev["h2d_bytes"] == host["h2d_bytes"] >= x.nbytes
+    assert dev["d2h_bytes"] == host["d2h_bytes"] + x.nbytes
+    assert dev["place_max_device_bytes"] == host["place_max_device_bytes"]
+    assert np.array_equal(runs[0].centers, runs[1].centers)
 
 
 def test_spans_land_on_the_profiler_host_plane(tmp_path):
